@@ -78,6 +78,7 @@ from repro.core.plan import resolve_plan                # noqa: E402
 from repro.core.topology import global_average, stack_like  # noqa: E402
 from repro.launch import hlo_analysis as ha             # noqa: E402
 from repro.optim import sgd                             # noqa: E402
+from repro.runtime import refuse_on_tpu                 # noqa: E402
 from benchmarks.common import Row, cls_setup, timed_run  # noqa: E402
 
 # deep-ish MLP: 7 layers x (w, b) = 14 leaves, so the per-leaf path pays
@@ -198,6 +199,7 @@ def _reduction_ab(rounds: int) -> List[Row]:
     import subprocess
     import sys
 
+    refuse_on_tpu("benchmarks/bench_bucketing.py")
     rows: List[Row] = []
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)
@@ -263,6 +265,7 @@ def _codec_ab(rounds: int) -> List[Row]:
     import subprocess
     import sys
 
+    refuse_on_tpu("benchmarks/bench_bucketing.py")
     rows: List[Row] = []
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)
@@ -373,6 +376,7 @@ def _sharded_ab(rounds: int) -> List[Row]:
     import subprocess
     import sys
 
+    refuse_on_tpu("benchmarks/bench_bucketing.py")
     rows: List[Row] = []
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)

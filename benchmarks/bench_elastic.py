@@ -55,6 +55,7 @@ from repro.core.theory import (effective_participants, thm32_bound,
 from repro.elastic import (FaultSchedule, elastic_restore,
                            save_elastic_checkpoint)
 from repro.optim import sgd
+from repro.runtime import refuse_on_tpu
 
 RECORDS: List[Dict] = []
 
@@ -217,6 +218,7 @@ def _reshape_row(setup, smoke: bool) -> Row:
 
 
 def _determinism_row(smoke: bool) -> Row:
+    refuse_on_tpu("benchmarks/bench_elastic.py")
     fs = FaultSchedule(DET_SPEC, TOPO, ("local", "pod", "global"),
                        seed=11, deadlines=DET_DEADLINES)
     here = hashlib.sha256(

@@ -40,6 +40,7 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from benchmarks.common import Row
+from repro.runtime import refuse_on_tpu
 
 ARCH = "yi-34b"
 PAGE_SIZE = 8
@@ -177,6 +178,7 @@ def _measure_flash(which: str, rounds: int) -> Dict:
 
 
 def _child(argv: List[str]) -> Dict:
+    refuse_on_tpu("benchmarks/bench_serving.py")
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep \

@@ -61,6 +61,7 @@ from repro.autotune.probe import (PROBE_CAP_SMALL, ProbePoint, run_probe)
 from repro.configs.base import HierAvgParams
 from repro.core import HierTopology, Simulator
 from repro.core.theory import scheduled_wall
+from repro.runtime import refuse_on_tpu
 from repro.telemetry import (MetricsLogger, SpanTracer, validate_jsonl)
 
 RECORDS: List[Dict] = []
@@ -189,6 +190,7 @@ print(json.dumps({"off": off, "on": on, "n_stats": n_stats}))
 
 
 def _sharded_row(smoke: bool) -> Row:
+    refuse_on_tpu("benchmarks/bench_telemetry.py")
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(_REPO, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))

@@ -86,6 +86,8 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8").strip()
 
+    from repro.runtime import refuse_on_tpu
+    refuse_on_tpu("benchmarks/run.py")
     from benchmarks import (bench_adaptive_k2, bench_autotune,
                             bench_bucketing, bench_comm, bench_compression,
                             bench_elastic, bench_k1_s, bench_k2,

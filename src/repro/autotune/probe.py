@@ -189,6 +189,8 @@ def run_probe(points: Optional[Sequence[ProbePoint]] = None, *,
     """Measure every point in a FRESH subprocess (see module docstring)
     and optionally write the samples to ``out`` as the probe artifact
     ``autotune/calibrate.py`` consumes."""
+    from repro.runtime import refuse_on_tpu
+    refuse_on_tpu("repro.autotune.probe")
     points = list(points) if points is not None else default_grid(smoke)
     repo_src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))

@@ -83,9 +83,12 @@ class QInt8Reducer(Reducer):
 
     def compress(self, tree, state):
         if self.fused:
-            payload = [ops.qint8_pack(self._flat(leaf), self.block,
-                                      impl=self.impl)
-                       for leaf in jax.tree.leaves(tree)]
+            # the learner axes stay leading: the kernels take their rows
+            # by index, not by a reshape (kernels/qint8_pack.py)
+            payload = [ops.qint8_pack(leaf.reshape(
+                leaf.shape[:N_LEARNER_AXES] + (per_learner_size(leaf),)),
+                self.block, impl=self.impl)
+                for leaf in jax.tree.leaves(tree)]
         else:
             payload = [quantize_block(self._flat(leaf), self.block)
                        for leaf in jax.tree.leaves(tree)]

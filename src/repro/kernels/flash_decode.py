@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
 
 NEG_INF = -1.0e30
 
@@ -69,8 +68,12 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32)          # [G, D]
         k = k_ref[0, 0].astype(jnp.float32)          # [page, D]
         v = v_ref[0, 0].astype(jnp.float32)          # [page, D]
+        # f32 contractions at full precision, as the model computes: the
+        # tiles are a few rows, so the extra MXU passes cost nothing
+        # beside the page reads
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32) * scale   # [G, page]
 
         kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -87,6 +90,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
@@ -148,8 +152,8 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lens, qr, k_pages, v_pages)
 

@@ -9,12 +9,45 @@
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as kref
+
+# mesh axes a learner row is split over, major to minor (launch/train.py's
+# learner mesh); "fsdp" joins when a codec view merges the shards into the
+# rows (comm/bucket.py)
+_LEARNER_AXES = ("pod", "group", "local")
+
+
+def _per_row(fn, x, n_trailing: int):
+    """``fn(x)`` for a compiled kernel that maps each learner row of
+    ``x`` — one index into its leading ``x.ndim - n_trailing`` dims — on
+    its own.  The TPU compiler cannot partition a Mosaic kernel, so under
+    a learner mesh (``jax.set_mesh``) the kernel runs in a ``shard_map``
+    over the rows each device holds; without one it is called as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = tuple(a for a in _LEARNER_AXES if a in mesh.axis_names)
+    if not axes:
+        return fn(x)
+    lead = x.shape[:x.ndim - n_trailing]
+    rows = math.prod(lead)
+    learners = math.prod(mesh.shape[a] for a in axes)
+    if "fsdp" in mesh.axis_names and rows == learners * mesh.shape["fsdp"]:
+        axes += ("fsdp",)
+    elif rows != learners:
+        raise ValueError(
+            f"kernel operand {tuple(x.shape)} has {rows} rows; the mesh "
+            f"{dict(mesh.shape)} holds {learners} learners")
+    flat = x.reshape((rows,) + x.shape[len(lead):])
+    specs = jax.tree.map(lambda _: P(axes), jax.eval_shape(fn, flat))
+    out = jax.shard_map(fn, mesh=mesh, in_specs=P(axes), out_specs=specs,
+                        check_vma=False)(flat)
+    return jax.tree.map(lambda o: o.reshape(lead + o.shape[1:]), out)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -90,8 +123,9 @@ def topk_compress(x, k: int, *, impl: str = "xla", block_n: int = 1024,
             f"{tuple(x.shape)} (n={n}); use compaction='scan', lower "
             f"HierAvgParams.bucket_bytes, or fall back to impl='xla'")
     from repro.kernels.topk_compress import topk_compress as tk
-    return tk(x, k, block_n=block_n, compaction=compaction,
-              interpret=(impl == "pallas_interpret"))
+    fn = functools.partial(tk, k=k, block_n=block_n, compaction=compaction,
+                           interpret=(impl == "pallas_interpret"))
+    return fn(x) if impl == "pallas_interpret" else _per_row(fn, x, 1)
 
 
 def batched_qr(p, *, impl: str = "auto") -> jax.Array:
@@ -111,14 +145,16 @@ def batched_qr(p, *, impl: str = "auto") -> jax.Array:
     if impl == "xla":
         return kref.batched_qr_ref(p)
     from repro.kernels.batched_qr import batched_qr as bqr
-    return bqr(p, interpret=(impl == "pallas_interpret"))
+    if impl == "pallas_interpret":
+        return bqr(p, interpret=True)
+    return _per_row(bqr, p, 2)
 
 
 def qint8_pack(x, block: int, *, impl: str = "auto") -> jax.Array:
-    """Dispatchable fused quantize+pack: ``[rows, n] -> int8 [rows, nb,
-    block + 4]`` — one contiguous wire buffer (payload + bitcast scales)
-    so a qint8 bucket rides the collective as ONE message instead of
-    two.  Bit-identical across impls (the scale bytes are a bitcast);
+    """Dispatchable fused quantize+pack: ``[..., n] -> int8 [..., nb,
+    block + 4]`` (leading dims: learner rows) — one contiguous wire
+    buffer (payload + bitcast scales) so a qint8 bucket rides the
+    collective as ONE message instead of two.  Bit-identical across impls (the scale bytes are a bitcast);
     ``impl="auto"`` = Pallas on TPU, oracle elsewhere.
     """
     if impl == "auto":
@@ -126,18 +162,22 @@ def qint8_pack(x, block: int, *, impl: str = "auto") -> jax.Array:
     if impl == "xla":
         return kref.qint8_pack_ref(x, block)
     from repro.kernels.qint8_pack import qint8_pack as qp
-    return qp(x, block, interpret=(impl == "pallas_interpret"))
+    if impl == "pallas_interpret":
+        return qp(x, block, interpret=True)
+    return _per_row(functools.partial(qp, block=block), x, 1)
 
 
 def qint8_unpack(wire, n: int, *, impl: str = "auto") -> jax.Array:
-    """Inverse of :func:`qint8_pack`: ``int8 [rows, nb, block + 4] ->
-    fp32 [rows, n]``."""
+    """Inverse of :func:`qint8_pack`: ``int8 [..., nb, block + 4] ->
+    fp32 [..., n]``."""
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
         return kref.qint8_unpack_ref(wire, n)
     from repro.kernels.qint8_pack import qint8_unpack as qu
-    return qu(wire, n, interpret=(impl == "pallas_interpret"))
+    if impl == "pallas_interpret":
+        return qu(wire, n, interpret=True)
+    return _per_row(functools.partial(qu, n=n), wire, 2)
 
 
 def rwkv6_wkv(r, k, v, w, u, state, *, impl: str = "xla",

@@ -129,18 +129,18 @@ _QINT8_SCALE_BYTES = 4
 
 
 def qint8_pack_ref(x: jax.Array, block: int) -> jax.Array:
-    """Fused quantize+pack oracle: ``[rows, n] -> int8 [rows, nb,
+    """Fused quantize+pack oracle: ``[..., n] -> int8 [..., nb,
     block + 4]`` (int8 payload + bitcast fp32 scale per block — the wire
     format of kernels/qint8_pack.py).  Scale math is bit-identical to
     comm/quant.py ``quantize_block``; the zero-padded tail of the final
     partial block quantizes to zero.
     """
-    rows, n = x.shape
+    lead, n = x.shape[:-1], x.shape[-1]
     nb = -(-n // block)
     xb = x.astype(jnp.float32)
     if nb * block != n:
-        xb = jnp.pad(xb, ((0, 0), (0, nb * block - n)))
-    xb = xb.reshape(rows, nb, block)
+        xb = jnp.pad(xb, ((0, 0),) * len(lead) + ((0, nb * block - n),))
+    xb = xb.reshape(lead + (nb, block))
     scale = jnp.max(jnp.abs(xb), axis=-1, keepdims=True) / 127.0
     scale = jnp.maximum(scale, 1e-12)
     q = jnp.clip(jnp.round(xb / scale), -127, 127).astype(jnp.int8)
@@ -149,13 +149,13 @@ def qint8_pack_ref(x: jax.Array, block: int) -> jax.Array:
 
 
 def qint8_unpack_ref(wire: jax.Array, n: int) -> jax.Array:
-    """Inverse of :func:`qint8_pack_ref`: ``int8 [rows, nb, block + 4]
-    -> fp32 [rows, n]`` (padding tail sliced off)."""
-    rows, nb, width = wire.shape
+    """Inverse of :func:`qint8_pack_ref`: ``int8 [..., nb, block + 4]
+    -> fp32 [..., n]`` (padding tail sliced off)."""
+    lead, (nb, width) = wire.shape[:-2], wire.shape[-2:]
     block = width - _QINT8_SCALE_BYTES
     q = wire[..., :block].astype(jnp.float32)
     scale = jax.lax.bitcast_convert_type(wire[..., block:], jnp.float32)
-    return (q * scale[..., None]).reshape(rows, nb * block)[:, :n]
+    return (q * scale[..., None]).reshape(lead + (nb * block,))[..., :n]
 
 
 def rwkv6_wkv_ref(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
                 s_scr, *, block_t: int, t_chunks: int):
@@ -107,7 +105,8 @@ def rwkv6_wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((b * h, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rr, kk, vv, ww, uu, s0)
 

@@ -59,54 +59,37 @@ def default_hier_params(cfg: ArchConfig) -> HierAvgParams:
     return HierAvgParams(k1=4, k2=8)
 
 
-def train_case(cfg: ArchConfig, shape: InputShape, *, multi_pod: bool,
-               hier: Optional[HierAvgParams] = None,
-               remat: bool = True,
-               param_dtype=jnp.bfloat16,
-               sync_opt_state: bool = False,
-               use_constraints: bool = True) -> DryrunCase:
-    hier = hier or default_hier_params(cfg)
-    plan = hier.resolved_plan
-    lay = cfg.layout
-    mesh = make_hier_mesh(lay, multi_pod=multi_pod)
-    pods = PODS_MULTI if multi_pod else 1
-    topo = HierTopology(pods=pods, groups=lay.groups, local=lay.local)
+def learner_state_placement(state_struct, mesh: Mesh, shards=None,
+                            rules: Optional[PartitionRules] = None):
+    """Placement of a stacked-learner ``TrainState`` on a hier mesh
+    ``("pod", "group", "local", "fsdp", "model")``.
 
-    bundle = build(cfg, param_dtype=param_dtype, remat=remat)
-    optimizer = sgd(0.1)          # paper: plain SGD, step-decayed lr
-    rules = PartitionRules()
-    # fsdp>1: shard-aware bucket layout — buckets pack each device's
-    # shard slice and every level's mean lowers to RS+AG (comm/bucket.py)
-    shards = shard_plan(mesh, rules=rules) if lay.fsdp > 1 else None
-
-    # ---- state structure without allocation ----
-    state_struct = jax.eval_shape(
-        lambda k: init_state(topo, bundle.init, optimizer, k, plan=plan,
-                             shards=shards),
-        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    Returns ``(state_shardings, constraint_fn)``: NamedShardings for every
+    state leaf (params by the partition rules with the learner axes on
+    their three leading dims; optimizer state and reducer comm state
+    mirroring them), and the ``constraint_fn`` the round builder applies
+    after each grouped mean to keep GSPMD on that placement.
+    """
+    rules = rules or PartitionRules()
     pspecs = param_pspecs(state_struct.params, mesh, stacked_learners=True,
                           rules=rules)
-    opt_specs = jax.tree.map(
-        lambda leaf: safe_pspec(
-            P(*(("pod", "group", "local") + (None,) * (leaf.ndim - 3))),
-            leaf.shape, mesh),
-        state_struct.opt_state) if jax.tree.leaves(state_struct.opt_state) \
-        else state_struct.opt_state
+    params_treedef = jax.tree_util.tree_structure(state_struct.params)
     # momentum mirrors params: reuse param specs when structures match
-    try:
-        opt_specs = jax.tree.map(lambda s: s, pspecs) \
-            if (jax.tree_util.tree_structure(state_struct.opt_state)
-                == jax.tree_util.tree_structure(state_struct.params)) \
-            else opt_specs
-    except Exception:
-        pass
+    if jax.tree_util.tree_structure(state_struct.opt_state) \
+            == params_treedef:
+        opt_specs = pspecs
+    else:
+        opt_specs = jax.tree.map(
+            lambda leaf: safe_pspec(
+                P(*(("pod", "group", "local")
+                    + (None,) * (leaf.ndim - 3))),
+                leaf.shape, mesh),
+            state_struct.opt_state)
     # reducer comm state, per plan level: EF ref/err (and PowerSGD ref/err)
     # mirror the params tree exactly (same shapes, fp32 err), so they reuse
     # the params' specs — learner axes AND trailing fsdp/tp shards; PRNG
     # keys stay replicated, and PowerSGD's warm Q shards over the learner
     # axes only (its trailing [b, rank] dims are tiny)
-    params_treedef = jax.tree_util.tree_structure(state_struct.params)
-
     s_sz = int(mesh.shape["local"])
     f_sz = int(mesh.shape.get("fsdp", 1))
 
@@ -156,6 +139,56 @@ def train_case(cfg: ArchConfig, shape: InputShape, *, multi_pod: bool,
     state_shardings = jax.tree.map(
         lambda s: NamedSharding(mesh, s), state_specs,
         is_leaf=lambda x: isinstance(x, P))
+    param_shardings = state_shardings.params
+
+    def pin_learner_axes(leaf):
+        """Generic re-pin for trees that do NOT mirror the params
+        (bucket-space reductions, comm/bucket.py): learner axes
+        sharded, trailing bucket dims replicated (codec-view leaves
+        keep their fsdp shard via ``bucket_lead_spec``)."""
+        if getattr(leaf, "ndim", 0) < 3:
+            return leaf
+        return jax.lax.with_sharding_constraint(
+            leaf, NamedSharding(mesh, bucket_lead_spec(leaf)))
+
+    def constraint_fn(tree):
+        if jax.tree_util.tree_structure(tree) == params_treedef:
+            return jax.tree.map(jax.lax.with_sharding_constraint, tree,
+                                param_shardings)
+        return jax.tree.map(pin_learner_axes, tree)
+
+    return state_shardings, constraint_fn
+
+
+def train_case(cfg: ArchConfig, shape: InputShape, *, multi_pod: bool,
+               hier: Optional[HierAvgParams] = None,
+               remat: bool = True,
+               param_dtype=jnp.bfloat16,
+               sync_opt_state: bool = False,
+               use_constraints: bool = True) -> DryrunCase:
+    hier = hier or default_hier_params(cfg)
+    plan = hier.resolved_plan
+    lay = cfg.layout
+    mesh = make_hier_mesh(lay, multi_pod=multi_pod)
+    pods = PODS_MULTI if multi_pod else 1
+    topo = HierTopology(pods=pods, groups=lay.groups, local=lay.local)
+
+    bundle = build(cfg, param_dtype=param_dtype, remat=remat)
+    optimizer = sgd(0.1)          # paper: plain SGD, step-decayed lr
+    rules = PartitionRules()
+    # fsdp>1: shard-aware bucket layout — buckets pack each device's
+    # shard slice and every level's mean lowers to RS+AG (comm/bucket.py)
+    shards = shard_plan(mesh, rules=rules) if lay.fsdp > 1 else None
+
+    # ---- state structure without allocation ----
+    state_struct = jax.eval_shape(
+        lambda k: init_state(topo, bundle.init, optimizer, k, plan=plan,
+                             shards=shards),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    state_shardings, constraint_fn = learner_state_placement(
+        state_struct, mesh, shards, rules)
+    if not use_constraints:
+        constraint_fn = None
 
     # ---- per-learner batch ----
     per_learner_b = shape.global_batch // topo.n_learners
@@ -174,33 +207,6 @@ def train_case(cfg: ArchConfig, shape: InputShape, *, multi_pod: bool,
     # assignment — the loader and the lowered case cannot disagree)
     from repro.data.loader import round_batch_shardings
     batch_shardings = round_batch_shardings(mesh, hier, batch_specs)
-
-    constraint_fn = None
-    if use_constraints:
-        param_shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                                       pspecs, is_leaf=lambda x:
-                                       isinstance(x, P))
-
-        def pin_learner_axes(leaf):
-            """Generic re-pin for trees that do NOT mirror the params
-            (bucket-space reductions, comm/bucket.py): learner axes
-            sharded, trailing bucket dims replicated (codec-view leaves
-            keep their fsdp shard via ``bucket_lead_spec``)."""
-            if getattr(leaf, "ndim", 0) < 3:
-                return leaf
-            return jax.lax.with_sharding_constraint(
-                leaf, NamedSharding(mesh, bucket_lead_spec(leaf)))
-
-        def constraint_fn(tree):
-            try:
-                return jax.tree.map(jax.lax.with_sharding_constraint, tree,
-                                    param_shardings)
-            except Exception:
-                pass
-            try:
-                return jax.tree.map(pin_learner_axes, tree)
-            except Exception:
-                return tree
 
     round_fn = make_hier_round(bundle.loss_fn, optimizer, hier,
                                sync_opt_state=sync_opt_state,

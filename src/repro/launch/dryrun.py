@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x input shape) on the
 production meshes, record memory/cost/collective analysis for §Roofline.
 
@@ -11,19 +8,18 @@ Run:
 
 Exit status is non-zero if any case fails to lower/compile — a failure here
 is a sharding bug in the framework, per the assignment.
-"""  # noqa: E402
+"""
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import argparse
+import json
+import os
+import time
+import traceback
 
-import jax           # noqa: E402
-
-from repro.configs import ALL_ARCHS, INPUT_SHAPES, get_config  # noqa: E402
-from repro.launch.cases import build_case, parse_layout        # noqa: E402
-from repro.launch import hlo_analysis as ha                    # noqa: E402
-from repro.launch.analytic import analytic_roofline            # noqa: E402
+from repro.configs import ALL_ARCHS, INPUT_SHAPES, get_config
+from repro.launch.cases import build_case, parse_layout
+from repro.launch import hlo_analysis as ha
+from repro.launch.analytic import analytic_roofline
 
 
 def applicable_shapes(cfg):
@@ -124,6 +120,9 @@ def main() -> None:
                          "lower the plan the cost-aware search recommends "
                          "for each arch instead of --plan/--k1/--k2")
     args = ap.parse_args()
+    # the production meshes as host devices; set before the first JAX
+    # call, which creates the backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     cases = []
     archs = ALL_ARCHS if (args.all or not args.arch) else [args.arch]

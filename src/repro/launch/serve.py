@@ -1,30 +1,39 @@
-"""Batched serving driver (reduced configs run end-to-end on CPU).
+"""Batched serving driver, at the published widths unless ``--reduced``
+(the tiny smoke-test widths that run end-to-end on CPU).
 
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-1.6b --reduced \
       --requests 6 --prompt-len 16 --max-new 8
 
   # paged continuous batching (token-level slot refill):
-  PYTHONPATH=src python -m repro.launch.serve --arch yi-34b --paged \
-      --requests 8 --slots 4 --block-size 16 --max-new 8
+  PYTHONPATH=src python -m repro.launch.serve --arch yi-34b --reduced \
+      --paged --requests 8 --slots 4 --block-size 16 --max-new 8
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.models import build
-from repro.serve import GenerationConfig, PagedServeEngine, ServeEngine
+from repro.runtime import use_compile_cache
+from repro.serve import (GenerationConfig, PagedServeEngine, RequestResult,
+                         ServeEngine)
 from repro.telemetry import MetricsLogger
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None
+         ) -> Tuple[object, List[RequestResult]]:
+    """Serve a seeded request queue from command-line ``argv`` (default
+    ``sys.argv[1:]``); returns the engine and the results."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny smoke-test widths (ArchConfig.reduced) "
+                         "instead of the published ones")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -46,8 +55,9 @@ def main() -> None:
                     help="write telemetry rows (serve_step per decode "
                          "step on the paged path, serve_summary per "
                          "queue) to this JSONL file")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -93,6 +103,7 @@ def main() -> None:
     if logger is not None:
         logger.close()
         print(f"telemetry rows -> {args.metrics_out}")
+    return engine, results
 
 
 if __name__ == "__main__":

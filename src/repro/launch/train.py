@@ -1,9 +1,12 @@
 """Distributed Hier-AVG training driver.
 
-On real hardware this runs the exact programs the dry-run lowers; on this
-CPU container it runs REDUCED configs end-to-end (``--reduced``, default)
-so the full path — config, topology, loader, rounds, checkpointing,
-LR decay — is exercised for real.
+Runs a zoo architecture at its published widths through the Hier-AVG
+round: config, topology, loader, rounds, checkpointing, LR decay.  The
+learners go one per device when ``learners x fsdp`` devices are present
+(a ``("pod", "group", "local", "fsdp", "model")`` mesh built from the
+devices), and are stacked on the first device otherwise.  ``--layers``
+cuts the depth only; ``--reduced`` swaps in the tiny smoke-test widths
+for CPU runs.
 
   PYTHONPATH=src python -m repro.launch.train --arch hymba-1.5b --reduced \
       --rounds 5 --k1 2 --k2 4 --learners 4 --s 2
@@ -14,9 +17,12 @@ import argparse
 import dataclasses
 import time
 from contextlib import nullcontext
+from typing import Any, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh
 
 from repro.checkpoint import save_checkpoint
 from repro.comm import DEFAULT_BUCKET_BYTES
@@ -24,16 +30,55 @@ from repro.configs import HierAvgParams, get_config
 from repro.core import (HierTopology, init_state, make_hier_round,
                         unstack_first)
 from repro.data.loader import HierDataLoader
-from repro.data.synthetic import make_markov_task, markov_lm_batch
+from repro.launch.cases import learner_state_placement
 from repro.models import build
 from repro.models.stubs import make_train_batch
 from repro.optim import sgd, step_decay_lr
+from repro.parallel.sharding import shard_plan
+from repro.runtime import use_compile_cache
 
 
-def main() -> None:
+class TrainRun(NamedTuple):
+    """What an in-process caller of :func:`main` gets back."""
+    state: Any            # TrainState after the last round
+    losses: List[float]   # mean loss of each round
+    round_fn: Any         # the jitted round (``.lower`` for its HLO)
+    batch: Any            # the last round batch
+    mesh: Optional[Mesh]  # the learner mesh, None when stacked
+
+
+def learner_mesh(topo: HierTopology, fsdp: int,
+                 devices: Sequence) -> Optional[Mesh]:
+    """One learner (times ``fsdp`` shards) per device on a
+    ``("pod", "group", "local", "fsdp", "model")`` mesh when
+    ``learners x fsdp`` devices are given; None — learners stacked on
+    the first device — when the job fits on one device and there are too
+    few for the mesh."""
+    need = topo.n_learners * fsdp
+    if need > 1 and len(devices) >= need:
+        return Mesh(np.array(devices[:need]).reshape(
+            topo.pods, topo.groups, topo.local, fsdp, 1),
+            ("pod", "group", "local", "fsdp", "model"))
+    if fsdp > 1:
+        raise ValueError(
+            f"--fsdp {fsdp} needs {need} devices, have {len(devices)} "
+            f"(set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{need} on CPU)")
+    return None
+
+
+def main(argv: Optional[Sequence[str]] = None,
+         devices: Optional[Sequence] = None) -> TrainRun:
+    """Train from command-line ``argv`` (default ``sys.argv[1:]``) on
+    ``devices`` (default ``jax.devices()``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny smoke-test widths (ArchConfig.reduced) "
+                         "instead of the published ones")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers; widths "
+                         "stay as configured")
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--k1", type=int, default=2)
     ap.add_argument("--k2", type=int, default=4)
@@ -63,9 +108,7 @@ def main() -> None:
                          "(parallel/sharding.py ShardPlan): bucketed "
                          "reductions pack shard-local runs and lower "
                          "each level's mean to reduce-scatter + "
-                         "all-gather.  Needs learners*fsdp devices "
-                         "(XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=N on CPU)")
+                         "all-gather.  Needs learners*fsdp devices")
     ap.add_argument("--autotune", default=None, metavar="CALIB_JSON",
                     help="calibration artifact (autotune/calibrate.py); "
                          "runs the cost-aware plan search over the real "
@@ -99,35 +142,26 @@ def main() -> None:
                          "/ Perfetto device timeline)")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    assert args.learners % args.s == 0
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    if args.learners % args.s:
+        ap.error(f"--learners {args.learners} is not a multiple of "
+                 f"--s {args.s}")
     topo = HierTopology(pods=1, groups=args.learners // args.s,
                         local=args.s)
     hier = HierAvgParams(k1=args.k1, k2=args.k2, reducer=args.reducer,
                          plan=args.plan, bucket_bytes=args.bucket_bytes,
                          overlap=not args.no_overlap)
     bundle = build(cfg)
-    shards = None
-    if args.fsdp > 1:
-        import numpy as np
-        from jax.sharding import Mesh
-
-        from repro.parallel.sharding import shard_plan
-        need = topo.n_learners * args.fsdp
-        devs = jax.devices()
-        assert len(devs) >= need, (
-            f"--fsdp {args.fsdp} needs {need} devices, have {len(devs)} "
-            f"(set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{need} on CPU)")
-        mesh = Mesh(
-            np.array(devs[:need]).reshape(
-                1, topo.groups, topo.local, args.fsdp, 1),
-            ("pod", "group", "local", "fsdp", "model"))
-        shards = shard_plan(mesh)
+    devices = jax.devices() if devices is None else devices
+    mesh = learner_mesh(topo, args.fsdp, devices)
+    shards = shard_plan(mesh) if args.fsdp > 1 else None
     controller = None
     if args.autotune:
         from repro.autotune import (Calibration, CostAwarePlan,
@@ -167,7 +201,8 @@ def main() -> None:
         return make_train_batch(k, cfg, batch=n, seq_len=args.seq)
 
     loader = HierDataLoader(sample, topo=topo, hier=hier,
-                            per_learner_batch=args.batch, seed=args.seed)
+                            per_learner_batch=args.batch, seed=args.seed,
+                            mesh=mesh)
     faults = None
     if args.faults:
         from repro.core.theory import level_reduction_seconds
@@ -187,15 +222,29 @@ def main() -> None:
                     drop_prob=1.0 - float(f))[2]
                 for lvl, f in zip(plan.levels, fracs))
 
+    def init(k):
+        return init_state(topo, bundle.init, optimizer, k, plan=plan,
+                          shards=shards)
+
+    state_shardings = constraint_fn = None
+    if mesh is not None:
+        state_shardings, constraint_fn = learner_state_placement(
+            jax.eval_shape(init, key), mesh, shards)
+    hier_round = make_hier_round(bundle.loss_fn, optimizer, hier,
+                                 shards=shards, constraint_fn=constraint_fn,
+                                 elastic=faults is not None,
+                                 telemetry=args.telemetry or None)
+    if mesh is not None:
+        # traced with the mesh in context, the codec kernels run on the
+        # learner rows each device holds (kernels/ops.py)
+        def hier_round(*a, _round=hier_round):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return _round(*a)
     # donate the carried TrainState (params/opt_state/EF update in place —
     # no doubled peak memory); the loop only ever uses the returned state
-    round_fn = jax.jit(make_hier_round(bundle.loss_fn, optimizer, hier,
-                                       shards=shards,
-                                       elastic=faults is not None,
-                                       telemetry=args.telemetry or None),
+    round_fn = jax.jit(hier_round, out_shardings=(state_shardings, None),
                        donate_argnums=(0,))
-    state = init_state(topo, bundle.init, optimizer, key, plan=plan,
-                       shards=shards)
+    state = jax.jit(init, out_shardings=state_shardings)(key)
 
     from repro.telemetry import MetricsLogger, SpanTracer
     logger = MetricsLogger(args.metrics_out) if args.metrics_out else None
@@ -222,7 +271,10 @@ def main() -> None:
 
     print(f"Hier-AVG: {topo.describe()}  plan={plan.describe()} "
           f"arch={cfg.name}"
-          + (f"  faults={faults.describe()}" if faults else ""))
+          + (f"  faults={faults.describe()}" if faults else "")
+          + (f"  mesh={dict(mesh.shape)}" if mesh is not None
+             else f"  stacked on {devices[0]}"))
+    losses = []
     for r in range(args.rounds):
         t0 = time.time()
         drec = None
@@ -246,6 +298,7 @@ def main() -> None:
                 # (the old per-key float() calls each blocked)
                 m = jax.device_get(metrics)
         wall = time.time() - t0
+        losses.append(float(m["loss"]))
         if tracer and modeled_phases:
             tracer.add_modeled_children(drec, modeled_phases)
         if faults is not None:
@@ -297,6 +350,7 @@ def main() -> None:
         save_checkpoint(args.ckpt, unstack_first(state.params),
                         step=int(state.step))
         print(f"saved averaged model to {args.ckpt}")
+    return TrainRun(state, losses, round_fn, batch, mesh)
 
 
 if __name__ == "__main__":

@@ -14,6 +14,8 @@ Callers are responsible for forcing >= 8 host devices
 """
 from __future__ import annotations
 
+import re
+
 from typing import Dict, Tuple
 
 import jax
@@ -163,6 +165,17 @@ def count_allreduce_ops(hlo_text: str) -> int:
     """All-reduce ops in a compiled module (sync or async spelling) —
     the program-size metric the A/B and the overlap test both gate on."""
     return hlo_text.count("all-reduce(") + hlo_text.count("all-reduce-start(")
+
+
+def count_allreduce_operands(hlo_text: str) -> int:
+    """Arrays reduced by all-reduce ops in a compiled module.  XLA's
+    all-reduce combiner merges independent all-reduces into one op over a
+    tuple of operands, so the op count says how many collectives were
+    issued and this count says how many arrays they carried."""
+    n = 0
+    for m in re.finditer(r"all-reduce(?:-start)?\(([^)]*)\)", hlo_text):
+        n += m.group(1).count("%")
+    return n
 
 
 def count_collective_ops(hlo_text: str) -> Dict[str, int]:

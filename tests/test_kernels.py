@@ -297,6 +297,25 @@ def test_qint8_pack_xla_impl_is_oracle():
         np.asarray(ref.qint8_unpack_ref(w, 300)))
 
 
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+def test_qint8_pack_keeps_learner_axes(impl):
+    """Stacked learner axes ``[P, G, S, n]`` pack row by row: the wire
+    and the round trip equal those of the ``[P*G*S, n]`` rows, with the
+    learner axes kept."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 2, 2, 300))
+    pack = jax.jit(lambda x: ops.qint8_pack(x, 64, impl=impl))
+    unpack = jax.jit(lambda w: ops.qint8_unpack(w, 300, impl=impl))
+    w = pack(x)
+    assert w.shape == (1, 2, 2, 5, 68)
+    np.testing.assert_array_equal(
+        np.asarray(w).reshape(4, 5, 68), np.asarray(pack(x.reshape(4, 300))))
+    back = unpack(w)
+    assert back.shape == x.shape
+    np.testing.assert_array_equal(
+        np.asarray(back).reshape(4, 300),
+        np.asarray(unpack(w.reshape(4, 5, 68))))
+
+
 try:
     from hypothesis import given, settings as _csettings
     import hypothesis.strategies as _cst
@@ -531,3 +550,47 @@ def test_wkv_kernel_matches_model_decode_semantics():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(S_k), np.asarray(S), atol=1e-4,
                                rtol=1e-4)
+
+
+_PER_ROW_CHILD = r"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.kernels import ops, ref
+mesh = Mesh(np.array(jax.devices()).reshape(1, 2, 2, 2, 1),
+            ("pod", "group", "local", "fsdp", "model"))
+x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024))
+L = ("pod", "group", "local")
+cases = [  # (fn, operand, trailing dims, operand placement)
+    (lambda a: ref.qint8_pack_ref(a, 128), x[:4], 1, P(L)),     # learners
+    (lambda a: ref.topk_compress_ref(a, 16), x, 1, P(L + ("fsdp",))),
+    (ref.batched_qr_ref, x[:4].reshape(1, 2, 2, 256, 4), 2, P(*L)),
+]
+for fn, a, t, spec in cases:
+    a = jax.device_put(a, NamedSharding(mesh, spec))
+    def body(v, fn=fn, t=t):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return ops._per_row(fn, v, t)
+    got, want = jax.jit(body)(a), jax.jit(fn)(a)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and bool(jnp.array_equal(g, w)), fn
+    assert "manual_computation" in jax.jit(body).lower(a).as_text()
+    bare = jax.jit(lambda v, fn=fn, t=t: ops._per_row(fn, v, t))
+    assert "manual_computation" not in bare.lower(a).as_text()
+print("ok")
+"""
+
+
+def test_per_row_splits_compiled_kernels_over_the_learner_mesh():
+    """Mosaic kernels cannot be partitioned by the compiler, so under a
+    learner mesh ``ops`` runs them in a shard_map over the rows each
+    device holds — learner rows, or learner x fsdp rows of a codec view —
+    and calls them as is without one.  Row-wise oracles stand in for the
+    kernels: the split must not change a value."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    r = subprocess.run([sys.executable, "-c", _PER_ROW_CHILD], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-3000:]

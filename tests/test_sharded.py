@@ -23,6 +23,7 @@ import warnings
 
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.parallel.sharding import (PSpecDropWarning, ShardPlan,
                                      replica_groups, resolve_pspec,
@@ -309,14 +310,6 @@ def test_level_replica_groups_matches_plan_axes():
 
 # ------------- safe_pspec non-dividing drop (regression) ------------- #
 
-def _abstract_mesh(sizes, names):
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:                          # older signature
-        return AbstractMesh(tuple(sizes), tuple(names))
-
-
 def test_safe_pspec_surfaces_nondividing_model_zoo_shapes():
     """The shapes that historically hit the silent-replication fallback:
     hymba's 25 attention heads vs TP-16 and seamless' 256206-token vocab
@@ -324,7 +317,7 @@ def test_safe_pspec_surfaces_nondividing_model_zoo_shapes():
     resolve_pspec must expose exactly which axes fell off, so layout and
     billing key off the resolved spec."""
     from jax.sharding import PartitionSpec as P
-    mesh = _abstract_mesh((2, 16), ("fsdp", "model"))
+    mesh = AbstractMesh((2, 16), ("fsdp", "model"))
     # hymba: 25 heads -> head-stacked (25, 128) leaf, TP on the head dim
     resolved, dropped = resolve_pspec(P("model", None), (25, 128), mesh)
     assert tuple(resolved) == (None, None)
@@ -348,7 +341,7 @@ def test_shard_plan_mirrors_safe_pspec_drop():
     """ShardPlan.leaf_shard_dim (what the bucket layout packs from) and
     the resolve_pspec drop agree: a non-dividing leaf stays flat, a
     dividing one shards its rules-resolved dim."""
-    mesh = _abstract_mesh((1, 2, 2, 2, 1), _HIER_NAMES)
+    mesh = AbstractMesh((1, 2, 2, 2, 1), _HIER_NAMES)
     sp = ShardPlan(mesh=mesh)
     # hymba-style head-count leaf: fallback (fsdp, model) on (25, 128),
     # 25 % 2 != 0 -> replicated, exactly the safe_pspec drop

@@ -16,7 +16,7 @@ from repro.data.synthetic import (make_classification_task, make_markov_task,
 from repro.optim import (adamw, clip_by_global_norm, constant_lr, cosine_lr,
                          global_norm, sgd, step_decay_lr)
 from repro.parallel.sharding import PartitionRules, safe_pspec
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 
 # ------------------------------ optim -------------------------------- #
@@ -188,22 +188,12 @@ def test_spec_leading_axes_stacked():
     assert tuple(s) == (None, "fsdp", "model")
 
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: ((name, size), ...) pairs vs the
-    newer (sizes, names) signature."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(sizes), tuple(names))
-
-
 def test_safe_pspec_drops_nondivisible():
-    mesh = _abstract_mesh((1, 1), ("data", "model"))
+    mesh = AbstractMesh((1, 1), ("data", "model"))
     # size-1 axes divide everything
     s = safe_pspec(P("data", "model"), (25, 7), mesh)
     assert tuple(s) == ("data", "model")
-    mesh4 = _abstract_mesh((2, 2), ("data", "model"))
+    mesh4 = AbstractMesh((2, 2), ("data", "model"))
     s = safe_pspec(P("data", "model"), (25, 8), mesh4)
     assert tuple(s) == (None, "model")
     # tuple axes multiply

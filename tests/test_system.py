@@ -45,8 +45,8 @@ def test_hier_avg_trains_reduced_lm():
 def test_train_driver_cli():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "rwkv6-1.6b",
-         "--rounds", "2", "--k1", "1", "--k2", "2", "--learners", "2",
-         "--s", "2", "--batch", "2", "--seq", "16"],
+         "--reduced", "--rounds", "2", "--k1", "1", "--k2", "2",
+         "--learners", "2", "--s", "2", "--batch", "2", "--seq", "16"],
         capture_output=True, text=True, env=ENV, cwd=ROOT, timeout=420)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "round   1" in out.stdout
@@ -55,7 +55,7 @@ def test_train_driver_cli():
 def test_serve_driver_cli():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch",
-         "qwen2-vl-2b", "--requests", "3", "--slots", "2",
+         "qwen2-vl-2b", "--reduced", "--requests", "3", "--slots", "2",
          "--prompt-len", "8", "--max-new", "4"],
         capture_output=True, text=True, env=ENV, cwd=ROOT, timeout=420)
     assert out.returncode == 0, out.stderr[-2000:]
