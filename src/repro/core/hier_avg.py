@@ -37,6 +37,7 @@ from repro.core.plan import (PlanLike, ReductionLevel, ReductionPlan,
 from repro.core.topology import (HierTopology, average_over, stack_like,
                                  where_active)
 from repro.optim import Optimizer
+from repro.telemetry.spans import reduce_scope
 
 
 class TrainState(NamedTuple):
@@ -197,33 +198,38 @@ def _make_reduce(constraint_fn, sync_opt_state):
     untouched across the missed fire (``where_active`` select) — a
     learner that missed a reduction neither contributes to nor observes
     it.  ``active=None`` is the dense path, bit-identical to before.
+
+    The whole reduction, bucket pack and unpack, codec, grouped mean and
+    finalize, runs under the scope ``reduce.<level>``
+    (telemetry/spans.py), so the device trace bills it to its level.
     """
 
     def reduce(level: ReductionLevel, state: TrainState,
                active=None) -> TrainState:
-        avg_fn = lambda tree, cf=None, specs=None: average_over(  # noqa: E731
-            tree, level.axes, cf, specs, active)
-        if level.reducer.stateful:
-            params, lvl_cs = reduce_with(
-                level.reducer, avg_fn, state.params,
-                state.comm_state[level.name], constraint_fn)
+        with jax.named_scope(reduce_scope(level.name)):
+            avg_fn = lambda tree, cf=None, specs=None: average_over(  # noqa: E731
+                tree, level.axes, cf, specs, active)
+            if level.reducer.stateful:
+                params, lvl_cs = reduce_with(
+                    level.reducer, avg_fn, state.params,
+                    state.comm_state[level.name], constraint_fn)
+                if active is not None:
+                    lvl_cs = where_active(active, lvl_cs,
+                                          state.comm_state[level.name])
+                comm_state = dict(state.comm_state)
+                comm_state[level.name] = lvl_cs
+            else:
+                params, _ = reduce_with(level.reducer, avg_fn, state.params,
+                                        (), constraint_fn)
+                comm_state = state.comm_state
             if active is not None:
-                lvl_cs = where_active(active, lvl_cs,
-                                      state.comm_state[level.name])
-            comm_state = dict(state.comm_state)
-            comm_state[level.name] = lvl_cs
-        else:
-            params, _ = reduce_with(level.reducer, avg_fn, state.params,
-                                    (), constraint_fn)
-            comm_state = state.comm_state
-        if active is not None:
-            params = where_active(active, params, state.params)
-        if sync_opt_state:
-            opt = avg_fn(state.opt_state, constraint_fn)
-            if active is not None:
-                opt = where_active(active, opt, state.opt_state)
-            state = state._replace(opt_state=opt)
-        return state._replace(params=params, comm_state=comm_state)
+                params = where_active(active, params, state.params)
+            if sync_opt_state:
+                opt = avg_fn(state.opt_state, constraint_fn)
+                if active is not None:
+                    opt = where_active(active, opt, state.opt_state)
+                state = state._replace(opt_state=opt)
+            return state._replace(params=params, comm_state=comm_state)
 
     return reduce
 
@@ -404,6 +410,8 @@ def make_hier_step(loss_fn: Callable, optimizer: Optimizer,
     for error-feedback reducers the round API reduces inner levels at
     outer boundaries too (subsumed in time, not in the nest), so
     trajectories differ by the compression of an already-averaged delta.
+    Each level's reduction runs under the scope ``reduce.<level>``, as in
+    the round API.
     """
     sgd_step = make_sgd_step(loss_fn, optimizer)
     p = resolve_plan(hier, reducer, plan, shards=shards)
@@ -433,11 +441,12 @@ def make_hier_step(loss_fn: Callable, optimizer: Optimizer,
             def reduce_branch(operand, level=level, avg_fn=avg_fn,
                               mask=mask):
                 pp, lcs = operand
-                out, ncs = reduce_with(level.reducer, avg_fn, pp, lcs,
-                                       constraint_fn)
-                if mask is not None:
-                    out = where_active(mask, out, pp)
-                    ncs = where_active(mask, ncs, lcs)
+                with jax.named_scope(reduce_scope(level.name)):
+                    out, ncs = reduce_with(level.reducer, avg_fn, pp, lcs,
+                                           constraint_fn)
+                    if mask is not None:
+                        out = where_active(mask, out, pp)
+                        ncs = where_active(mask, ncs, lcs)
                 return out, ncs
 
             params, lvl_cs = jax.lax.cond(

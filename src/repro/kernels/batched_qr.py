@@ -96,6 +96,7 @@ def batched_qr(p: jax.Array, *, interpret: bool = False) -> jax.Array:
 
     q = pl.pallas_call(
         functools.partial(_qr_kernel, r=r),
+        name="batched_qr",
         grid=(batch,),
         in_specs=[pl.BlockSpec((1, r_pad, a_pad), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, r_pad, a_pad), lambda i: (i, 0, 0)),

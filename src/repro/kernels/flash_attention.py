@@ -139,6 +139,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = pl.pallas_call(
         kernel,
         grid=grid,
+        name="flash_attention",
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), kv_map),

@@ -151,6 +151,7 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="flash_decode",
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

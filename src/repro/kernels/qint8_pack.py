@@ -106,6 +106,7 @@ def qint8_pack(x: jax.Array, block: int, *,
     width = block + _SCALE_BYTES
     wire = pl.pallas_call(
         functools.partial(_pack_kernel, block=block),
+        name="qint8_pack",
         grid=(rows, nb_pad // tile),
         in_specs=[pl.BlockSpec((1, tile, block), lambda r, t: (r, t, 0))],
         out_specs=pl.BlockSpec((1, tile, width), lambda r, t: (r, t, 0)),
@@ -129,6 +130,7 @@ def qint8_unpack(wire: jax.Array, n: int, *,
                    ((0, 0), (0, nb_pad - nb), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_unpack_kernel, block=block),
+        name="qint8_unpack",
         grid=(rows, nb_pad // tile),
         in_specs=[pl.BlockSpec((1, tile, width), lambda r, t: (r, t, 0))],
         out_specs=pl.BlockSpec((1, tile, block), lambda r, t: (r, t, 0)),

@@ -87,6 +87,7 @@ def rwkv6_wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                                t_chunks=t_chunks)
     y, sT = pl.pallas_call(
         kernel,
+        name="rwkv6_wkv",
         grid=(b * h, t_chunks),
         in_specs=[
             pl.BlockSpec((1, block_t, d), seq_map),   # r

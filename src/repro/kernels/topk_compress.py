@@ -215,6 +215,7 @@ def topk_compress(x: jax.Array, k: int, *, block_n: int = 1024,
     out_spec = pl.BlockSpec((1, out_rows, _LANE), lambda i: (i, 0, 0))
     vals, idx = pl.pallas_call(
         kernel,
+        name="topk_compress",
         grid=(rows,),
         in_specs=[pl.BlockSpec((1, n_pad // _LANE, _LANE),
                                lambda i: (i, 0, 0))],

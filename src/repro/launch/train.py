@@ -250,23 +250,7 @@ def main(argv: Optional[Sequence[str]] = None,
     logger = MetricsLogger(args.metrics_out) if args.metrics_out else None
     tracer = (SpanTracer(profile_dir=args.profile_dir)
               if (args.trace_out or args.profile_dir) else None)
-    modeled_phases = None
     if tracer is not None:
-        # one fused jit program cannot be host-decomposed: the per-level
-        # compress/collective split rides as MODELED child spans priced
-        # by the same bill every analytic surface reports
-        from repro.core.theory import level_reduction_seconds
-        tmpl = jax.eval_shape(
-            bundle.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
-        counts = dict(plan.counts_per_round())
-        modeled_phases = []
-        for lvl in plan.levels:
-            comm_s, compute_s, _ = level_reduction_seconds(
-                lvl, topo, tmpl, None)
-            if counts[lvl.name]:
-                modeled_phases += [
-                    (f"{lvl.name}/compress", compute_s * counts[lvl.name]),
-                    (f"{lvl.name}/collective", comm_s * counts[lvl.name])]
         tracer.start_profiler()
 
     print(f"Hier-AVG: {topo.describe()}  plan={plan.describe()} "
@@ -277,13 +261,12 @@ def main(argv: Optional[Sequence[str]] = None,
     losses = []
     for r in range(args.rounds):
         t0 = time.time()
-        drec = None
         with (tracer.span(f"round[{r}]", args={"round": r})
               if tracer else nullcontext()):
             with tracer.span("data") if tracer else nullcontext():
                 batch = loader.next_round()
             with (tracer.span("device", cat="device")
-                  if tracer else nullcontext()) as drec:
+                  if tracer else nullcontext()):
                 if faults is not None:
                     state, metrics = round_fn(
                         state, batch, jnp.asarray(faults.active(r)))
@@ -299,8 +282,6 @@ def main(argv: Optional[Sequence[str]] = None,
                 m = jax.device_get(metrics)
         wall = time.time() - t0
         losses.append(float(m["loss"]))
-        if tracer and modeled_phases:
-            tracer.add_modeled_children(drec, modeled_phases)
         if faults is not None:
             # host-side schedule mask: no extra device sync for fracs
             fracs = [float(f) for f in faults.active_frac(r)]

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import Params, apply_rope, dense_init, rmsnorm, rmsnorm_init
+from repro.telemetry.spans import ATTENTION
 
 NEG_INF = -1.0e30
 
@@ -135,6 +136,7 @@ def _project_qkv(p: Params, x, n_heads, n_kv_heads, head_dim):
     return q, k, v
 
 
+@jax.named_scope(ATTENTION)
 def gqa_attention(p: Params, x, cos, sin, *, n_heads: int, n_kv_heads: int,
                   head_dim: int, causal: bool = True, window: int = 0,
                   impl: str = "xla") -> jax.Array:

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import Params, dense_init
+from repro.telemetry.spans import SSM
 
 CONV_K = 4
 DT_RANK_DIV = 16
@@ -63,6 +64,7 @@ def _ssm_params(p: Params, u: jax.Array, state: int):
     return dt, B, C, A
 
 
+@jax.named_scope(SSM)
 def mamba_apply(p: Params, x: jax.Array, *, state: int,
                 ssm_state=None, conv_state=None, chunk: int = 256):
     """Full-sequence selective scan, time-chunked.

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import Params, activation, dense_init
+from repro.telemetry.spans import MLP
 
 
 def mlp_init(key, d_model: int, d_ff: int, act: str = "silu",
@@ -22,6 +23,7 @@ def mlp_init(key, d_model: int, d_ff: int, act: str = "silu",
     }
 
 
+@jax.named_scope(MLP)
 def mlp_apply(p: Params, x: jax.Array, act: str = "silu") -> jax.Array:
     fn = activation(act)
     if "w_gate" in p:

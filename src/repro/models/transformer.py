@@ -38,6 +38,7 @@ from repro.models.common import (Params, embed_init, dense_init,
                                  text_positions)
 from repro.models.mlp import mlp_apply, mlp_init
 from repro.models.moe import moe_apply, moe_init
+from repro.telemetry.spans import HEAD_LOSS
 
 
 # ===================================================================== #
@@ -295,9 +296,10 @@ def build_decoder_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
         h, aux = forward(params, embeds, positions)
         if off:
             h = h[:, off:]
-        logits = _unembed(params, cfg, h)
-        mask = batch.get("mask")
-        loss, metrics = softmax_cross_entropy(logits, batch["labels"], mask)
+        with jax.named_scope(HEAD_LOSS):
+            logits = _unembed(params, cfg, h)
+            loss, metrics = softmax_cross_entropy(logits, batch["labels"],
+                                                  batch.get("mask"))
         if cfg.uses_moe:
             aux = aux / max(1, n_main)
             loss = loss + cfg.router_aux_coef * aux
@@ -544,10 +546,9 @@ def build_rwkv_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
 
     def loss_fn(params, batch):
         h, _ = forward(params, params["embed"][batch["tokens"]])
-        logits = h @ params["lm_head"]
-        loss, metrics = softmax_cross_entropy(logits, batch["labels"],
-                                              batch.get("mask"))
-        return loss, metrics
+        with jax.named_scope(HEAD_LOSS):
+            return softmax_cross_entropy(h @ params["lm_head"],
+                                         batch["labels"], batch.get("mask"))
 
     def init_cache(batch: int, max_len: int = 0):
         states = [rwk.init_block_state(batch, cfg.d_model, H, hd)
@@ -643,9 +644,9 @@ def build_hymba_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
 
     def loss_fn(params, batch):
         h, _ = forward(params, params["embed"][batch["tokens"]])
-        logits = h @ params["lm_head"]
-        return softmax_cross_entropy(logits, batch["labels"],
-                                     batch.get("mask"))
+        with jax.named_scope(HEAD_LOSS):
+            return softmax_cross_entropy(h @ params["lm_head"],
+                                         batch["labels"], batch.get("mask"))
 
     def init_cache(batch: int, max_len: int = 0):
         states = [hyb.init_hymba_state(

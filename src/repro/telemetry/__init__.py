@@ -8,7 +8,8 @@ Three layers, composable and individually optional:
   rows (JSONL sink + in-memory ring buffer);
 * :mod:`repro.telemetry.spans` — :class:`SpanTracer`: host-side span
   timers with ``block_until_ready`` fencing, Chrome-trace export
-  (Perfetto-viewable), optional ``jax.profiler`` bracketing;
+  (Perfetto-viewable), each span a ``jax.profiler`` annotation; and
+  the names of the round's device-side scopes;
 * :mod:`repro.telemetry.gradstats` — device-side statistics inside the
   jitted round behind ``make_hier_round(..., telemetry=)``: per-level
   parameter divergence, gradient-norm variance, EF residual mass,

@@ -1,4 +1,5 @@
-"""Host-side span timers with device fencing, Chrome-trace export.
+"""Host-side span timers with device fencing, Chrome-trace export, and
+the names of the device-side scopes.
 
 :class:`SpanTracer` decomposes a training round into phases the host
 can honestly time:
@@ -10,16 +11,18 @@ can honestly time:
   ``block_until_ready`` and bill the device wait where it belongs;
 * ``host_sync`` — the device→host transfer (``jax.device_get``).
 
-One fused jit program cannot be decomposed from the host (XLA:CPU has
-no per-op timeline), so the compute / compress / collective split
-inside the device span is attached as MODELED child spans
-(:meth:`add_modeled_children`, ``cat="modeled"``) priced by
-``theory.level_reduction_seconds`` — clearly labeled so nobody mistakes
-an analytic bill for a measurement.  For real device profiles, pass
-``profile_dir`` (the ``--profile-dir`` flag): spans are then bracketed
-by ``jax.profiler`` trace annotations inside a
-``jax.profiler.start_trace`` session, viewable in TensorBoard/Perfetto
-alongside the XLA op timeline.
+Every span is also a ``jax.profiler`` trace annotation, so it lands on
+the host clock of whatever profiler session is active — the tracer's
+own (``profile_dir``, the ``--profile-dir`` flag, a
+``jax.profiler.start_trace`` session viewable in TensorBoard/Perfetto)
+or one a caller started — alongside the XLA op timeline.
+
+Inside the one fused jit program the host cannot split, each layer of
+the round runs under a ``jax.named_scope`` named here (:data:`SCOPES`,
+:func:`reduce_scope`): the names ride the ops' metadata through
+``grad``, ``scan`` and ``vmap`` into the device trace, where each op's
+``tf_op`` path says which layer it belongs to.  They change no
+computation.
 
 Export is the Chrome trace-event format (``{"traceEvents": [...]}``,
 complete events, microsecond timestamps) — drop ``trace.json`` onto
@@ -34,17 +37,31 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
+# device-side scopes of a training round (models/, core/hier_avg.py)
+ATTENTION = "attention"        # models/attention.gqa_attention
+MLP = "mlp"                    # models/mlp.mlp_apply
+SSM = "ssm"                    # models/mamba.mamba_apply
+HEAD_LOSS = "head_loss"        # unembedding + loss, models/transformer.py
+SCOPES = (ATTENTION, MLP, SSM, HEAD_LOSS)
+REDUCE = "reduce"              # one scope per plan level: reduce.<level>
+
+
+def reduce_scope(level: str) -> str:
+    """The scope of one plan level's reduction."""
+    return f"{REDUCE}.{level}"
+
 
 class SpanTracer:
-    """Collects host-side spans; optionally brackets them with
-    ``jax.profiler`` annotations when ``profile_dir`` is set."""
+    """Collects host-side spans, each also a ``jax.profiler`` trace
+    annotation; with ``profile_dir`` set, :meth:`start_profiler` opens a
+    profiler session of its own to record them in."""
 
     def __init__(self, profile_dir: Optional[str] = None):
         self.profile_dir = profile_dir
         self.spans: List[Dict[str, Any]] = []
         self._stack: List[Dict[str, Any]] = []
         self._t0 = time.perf_counter()
-        self._profiling = False
+        self._profiling = False     # the tracer's own profiler session
 
     # ------------------------------------------------------------ #
 
@@ -55,20 +72,17 @@ class SpanTracer:
     def span(self, name: str, cat: str = "host",
              args: Optional[Dict[str, Any]] = None):
         """Time a phase.  Yields the span record; on exit it carries
-        ``ts``/``dur`` (seconds relative to tracer start)."""
+        ``ts``/``dur`` (seconds relative to tracer start).  The span is
+        a trace annotation too, recorded by any active profiler
+        session."""
         rec = {"name": name, "cat": cat, "ts": self._now(), "dur": 0.0,
                "depth": len(self._stack), "args": dict(args or {})}
         self._stack.append(rec)
-        ann = None
-        if self._profiling:
-            import jax
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
+        import jax
         try:
-            yield rec
+            with jax.profiler.TraceAnnotation(name):
+                yield rec
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
             self._stack.pop()
             rec["dur"] = self._now() - rec["ts"]
             self.spans.append(rec)
@@ -78,21 +92,6 @@ class SpanTracer:
         billed the device wait, not just the async dispatch."""
         import jax
         jax.block_until_ready(value)
-
-    def add_modeled_children(self, parent: Dict[str, Any],
-                             phases: List
-                             ) -> None:
-        """Attach analytic child spans ``[(name, dur_s), ...]`` laid out
-        sequentially from ``parent``'s start, ``cat="modeled"`` — the
-        per-level compute/compress/collective decomposition the host
-        cannot measure inside one fused jit program."""
-        t = parent["ts"]
-        for name, dur in phases:
-            self.spans.append({
-                "name": name, "cat": "modeled", "ts": t,
-                "dur": float(dur), "depth": parent["depth"] + 1,
-                "args": {"modeled": True}})
-            t += float(dur)
 
     # ------------------------------------------------------------ #
     # jax.profiler bracketing (--profile-dir)
