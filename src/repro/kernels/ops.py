@@ -5,6 +5,9 @@
   * "pallas"           — compiled Pallas kernel (TPU target)
   * "pallas_interpret" — Pallas kernel body interpreted in Python on CPU
                          (correctness validation without hardware)
+
+Training attention's fused kernel (kernels/flash_attention.py) is chosen
+where it is called, in ``models/attention.full_attention``.
 """
 from __future__ import annotations
 
@@ -48,22 +51,6 @@ def _per_row(fn, x, n_trailing: int):
     out = jax.shard_map(fn, mesh=mesh, in_specs=P(axes), out_specs=specs,
                         check_vma=False)(flat)
     return jax.tree.map(lambda o: o.reshape(lead + o.shape[1:]), out)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    scale: Optional[float] = None, impl: str = "xla",
-                    interpret: Optional[bool] = None,
-                    block_q: int = 128, block_k: int = 128) -> jax.Array:
-    """Dispatchable attention: q [B,S,Hq,D], k/v [B,T,Hkv,D]."""
-    if interpret is not None:  # legacy call style from models.attention
-        impl = "pallas_interpret" if interpret else "pallas"
-    if impl == "xla":
-        return kref.flash_attention_ref(q, k, v, causal=causal,
-                                        window=window, scale=scale)
-    from repro.kernels.flash_attention import flash_attention as fa
-    return fa(q, k, v, causal=causal, window=window, scale=scale,
-              block_q=block_q, block_k=block_k,
-              interpret=(impl == "pallas_interpret"))
 
 
 def flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
@@ -182,8 +169,9 @@ def qint8_unpack(wire, n: int, *, impl: str = "auto") -> jax.Array:
 
 def rwkv6_wkv(r, k, v, w, u, state, *, impl: str = "xla",
               block_t: int = 64) -> Tuple[jax.Array, jax.Array]:
-    """Dispatchable WKV6: r/k/v/w [B,S,H,D], u [H,D], state [B,H,D,D]."""
-    if impl == "xla":
+    """Dispatchable WKV6: r/k/v/w [B,S,H,D], u [H,D], state [B,H,D,D].
+    ``"auto"`` is the XLA scan: the kernel has no backward."""
+    if impl in ("xla", "auto"):
         return kref.rwkv6_wkv_ref(r, k, v, w, u, state)
     from repro.kernels.rwkv6_wkv import rwkv6_wkv as wkv
     return wkv(r, k, v, w, u, state, block_t=block_t,

@@ -31,7 +31,7 @@ from repro.core import (HierTopology, init_state, make_hier_round,
                         unstack_first)
 from repro.data.loader import HierDataLoader
 from repro.launch.cases import learner_state_placement
-from repro.models import build
+from repro.models import attention, build
 from repro.models.stubs import make_train_batch
 from repro.optim import sgd, step_decay_lr
 from repro.parallel.sharding import shard_plan
@@ -259,6 +259,7 @@ def main(argv: Optional[Sequence[str]] = None,
           + (f"  mesh={dict(mesh.shape)}" if mesh is not None
              else f"  stacked on {devices[0]}"))
     losses = []
+    record = attention.DispatchRecord()
     for r in range(args.rounds):
         t0 = time.time()
         with (tracer.span(f"round[{r}]", args={"round": r})
@@ -267,11 +268,13 @@ def main(argv: Optional[Sequence[str]] = None,
                 batch = loader.next_round()
             with (tracer.span("device", cat="device")
                   if tracer else nullcontext()):
-                if faults is not None:
-                    state, metrics = round_fn(
-                        state, batch, jnp.asarray(faults.active(r)))
-                else:
-                    state, metrics = round_fn(state, batch)
+                # the first call traces the round: record its attention
+                with record if r == 0 else nullcontext():
+                    if faults is not None:
+                        state, metrics = round_fn(
+                            state, batch, jnp.asarray(faults.active(r)))
+                    else:
+                        state, metrics = round_fn(state, batch)
                 if tracer:
                     # bill the device wait to this span, not host_sync
                     tracer.fence(metrics)
@@ -295,6 +298,8 @@ def main(argv: Optional[Sequence[str]] = None,
               f"({wall:.1f}s, "
               f"{loader.tokens_per_round * args.seq} tokens)"
               + extra, flush=True)
+        if r == 0 and record.calls:
+            print(record.describe(), flush=True)
         if logger is not None or controller is not None:
             row = {"round": r, "loss": float(m["loss"]),
                    "accuracy": float(m.get("accuracy", float("nan"))),

@@ -9,7 +9,7 @@ from repro.models.transformer import (ModelBundle, build_decoder_lm,
 
 
 def build(cfg: ArchConfig, *, param_dtype=jnp.float32, compute_dtype=None,
-          remat: bool = False, impl: str = "xla",
+          remat: bool = False, impl: str = "auto",
           rolling_decode: bool = False,
           cache_dtype=jnp.bfloat16,
           decode_impl: str = "auto") -> ModelBundle:

@@ -16,12 +16,14 @@ Caches:
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import Params, apply_rope, dense_init, rmsnorm, rmsnorm_init
+from repro.kernels import flash_attention as fa
+from repro.models.common import (Params, apply_rope, dense_init, rmsnorm,
+                                 rmsnorm_init)
 from repro.telemetry.spans import ATTENTION
 
 NEG_INF = -1.0e30
@@ -81,21 +83,107 @@ def _chunked_causal_attend(q, k, v, *, window: int, scale, q_chunk: int):
     return jnp.moveaxis(out, 0, 1).reshape(b, s, hq, d)
 
 
+class AttentionCall(NamedTuple):
+    """One full-sequence attention call, recorded as it is traced (a
+    scanned layer stack is traced once for all its layers)."""
+    path: str            # "fused" (kernels/flash_attention.py) or "xla"
+    why: str             # on "xla": the condition that kept it there
+    key_tiles: int       # fused: tiles computed, per sequence and kv head
+    all_tiles: int       # fused: tiles of the whole s x s grid
+
+
+class DispatchRecord:
+    """The attention calls traced while it is active (``with``): each
+    call's path, and on the fused path its share of key tiles."""
+
+    def __init__(self):
+        self.calls: List[AttentionCall] = []
+
+    def __enter__(self) -> "DispatchRecord":
+        _RECORDS.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _RECORDS.remove(self)
+
+    def describe(self) -> str:
+        """One line: the traced calls on the fused path and their share
+        of key tiles, and why the others stayed on XLA."""
+        fused = [c for c in self.calls if c.path == "fused"]
+        line = (f"attention: fused in {len(fused)} of {len(self.calls)} "
+                f"traced calls")
+        if fused:
+            done = sum(c.key_tiles for c in fused)
+            every = sum(c.all_tiles for c in fused)
+            line += (f", {done} of {every} key tiles "
+                     f"({100 * done / every:.1f}%)")
+        whys = sorted({c.why for c in self.calls if c.path == "xla"})
+        return line + (f"; XLA: {', '.join(whys)}" if whys else "")
+
+
+_RECORDS: List[DispatchRecord] = []
+
+
+def _record(*call) -> None:
+    for rec in _RECORDS:
+        rec.calls.append(AttentionCall(*call))
+
+
+def _xla_reason(q, k, *, causal, window, q_offset, extra_mask,
+                impl) -> str:
+    """Why a call cannot take the fused kernel; "" when it can."""
+    s, t, d = q.shape[1], k.shape[1], q.shape[-1]
+    if not impl.startswith("pallas"):
+        return "not a TPU" if impl == "auto" else f"impl={impl}"
+    if not causal:
+        return "not causal"
+    if extra_mask is not None:
+        return "extra mask"
+    if not (isinstance(q_offset, int) and q_offset == 0):
+        return "query offset"
+    if s != t:
+        return "queries != keys"
+    if d not in fa.HEAD_DIMS:
+        return f"head_dim {d}"
+    if s % fa.BLOCKS[-1]:
+        return f"sequence {s} does not tile"
+    g = q.shape[2] // k.shape[2]
+    if not fa.blocks(s, window, g, d):
+        return f"VMEM: {g} query heads a kv head"
+    if jax.sharding.get_abstract_mesh().axis_names:
+        return "under a mesh"          # a Mosaic kernel cannot be partitioned
+    return ""
+
+
 def full_attention(q, k, v, *, causal: bool = True, window: int = 0,
                    q_offset: int = 0, extra_mask: Optional[jax.Array] = None,
-                   scale: Optional[float] = None, impl: str = "xla"):
-    """Dispatchable attention; ``impl`` in {"xla", "pallas", "pallas_interpret"}."""
+                   scale: Optional[float] = None, impl: str = "auto"):
+    """Attention of q [B,S,Hq,D] over k/v [B,T,Hkv,D].
+
+    ``impl``: "auto" takes the fused block-sparse kernel
+    (kernels/flash_attention.py, forward and backward) on a TPU and XLA
+    elsewhere; "pallas"/"pallas_interpret" ask for the kernel (compiled /
+    interpreted), "xla" for the XLA code.  The kernel serves causal
+    (optionally windowed) self-attention over the whole sequence at a
+    static zero offset, where the sequence tiles, the head dim is one it
+    takes and a block fits VMEM, outside a device mesh; every other call
+    runs the XLA code.
+    Each call's path is recorded as it is traced (:class:`DispatchRecord`).
+    """
+    if impl == "auto" and jax.default_backend() == "tpu":
+        impl = "pallas"
+    why = _xla_reason(q, k, causal=causal, window=window, q_offset=q_offset,
+                      extra_mask=extra_mask, impl=impl)
+    if not why:
+        _record("fused", "", *fa.key_tiles(q.shape[1], window,
+                                           q.shape[2] // k.shape[2],
+                                           q.shape[-1]))
+        return fa.flash_attention(q, k, v, window=window, scale=scale,
+                                  interpret=(impl == "pallas_interpret"))
+    _record("xla", why, 0, 0)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    # q_offset may be a traced scalar (paged chunk prefill) — only the
-    # static-zero case is eligible for the offset-free fast paths
     static_zero_offset = isinstance(q_offset, int) and q_offset == 0
-    if impl.startswith("pallas") and causal and extra_mask is None \
-            and static_zero_offset:
-        from repro.kernels import ops as kops
-        return kops.flash_attention(
-            q, k, v, causal=True, window=window, scale=float(scale),
-            interpret=(impl == "pallas_interpret"))
     b, s, _, _ = q.shape
     t = k.shape[1]
     q_chunk = _pick_q_chunk(t)
@@ -139,7 +227,7 @@ def _project_qkv(p: Params, x, n_heads, n_kv_heads, head_dim):
 @jax.named_scope(ATTENTION)
 def gqa_attention(p: Params, x, cos, sin, *, n_heads: int, n_kv_heads: int,
                   head_dim: int, causal: bool = True, window: int = 0,
-                  impl: str = "xla") -> jax.Array:
+                  impl: str = "auto") -> jax.Array:
     """Train/prefill full-sequence path. cos/sin [B,S,head_dim//2]."""
     q, k, v = _project_qkv(p, x, n_heads, n_kv_heads, head_dim)
     if cos is not None:

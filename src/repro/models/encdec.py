@@ -52,7 +52,7 @@ def _dec_layer_init(key, cfg: ArchConfig, dtype):
 
 
 def build_encdec(cfg: ArchConfig, *, param_dtype=jnp.float32,
-                 compute_dtype=None, remat: bool = False, impl: str = "xla",
+                 compute_dtype=None, remat: bool = False, impl: str = "auto",
                  cache_dtype=jnp.bfloat16, **_unused) -> ModelBundle:
     compute_dtype = compute_dtype or param_dtype
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim

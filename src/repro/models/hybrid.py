@@ -36,7 +36,7 @@ def hymba_block_init(key, *, d_model: int, n_heads: int, n_kv_heads: int,
 
 def hymba_block_apply(p: Params, x, cos, sin, *, n_heads, n_kv_heads,
                       head_dim, ssm_state, window, eps, act,
-                      impl: str = "xla"):
+                      impl: str = "auto"):
     h = rmsnorm(p["ln_in"], x, eps)
     a = gqa_attention(p["attn"], h, cos, sin, n_heads=n_heads,
                       n_kv_heads=n_kv_heads, head_dim=head_dim,

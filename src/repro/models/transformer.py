@@ -217,7 +217,7 @@ def _split_layers(cfg: ArchConfig) -> Tuple[int, int]:
 
 def build_decoder_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
                      compute_dtype=None, remat: bool = False,
-                     impl: str = "xla", rolling_decode: bool = False,
+                     impl: str = "auto", rolling_decode: bool = False,
                      cache_dtype=jnp.bfloat16,
                      decode_impl: str = "auto") -> ModelBundle:
     """dense / moe / mla / vlm families.
@@ -514,7 +514,7 @@ def build_decoder_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
 
 def build_rwkv_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
                   compute_dtype=None, remat: bool = False,
-                  impl: str = "xla", **_unused) -> ModelBundle:
+                  impl: str = "auto", **_unused) -> ModelBundle:
     compute_dtype = compute_dtype or param_dtype
     H, hd = cfg.ssm_heads, cfg.resolved_head_dim
 
@@ -600,7 +600,7 @@ def build_rwkv_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
 
 def build_hymba_lm(cfg: ArchConfig, *, param_dtype=jnp.float32,
                    compute_dtype=None, remat: bool = False,
-                   impl: str = "xla", cache_dtype=jnp.bfloat16,
+                   impl: str = "auto", cache_dtype=jnp.bfloat16,
                    **_unused) -> ModelBundle:
     compute_dtype = compute_dtype or param_dtype
     kw = dict(n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,

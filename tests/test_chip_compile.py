@@ -10,6 +10,7 @@ only one process may load the TPU library, and it keeps it until exit.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +76,102 @@ def test_flash_decode_compiles_at_qwen2_vl_2b_serve_shapes(one_chip):
         ((cfg.n_kv_heads, n_pages, page, d), jnp.bfloat16),
         ((slots, per_seq), jnp.int32),
         ((slots,), jnp.int32))
+
+
+# the cells' attention layers: (heads, kv heads, head dim, window) of
+# hymba-1.5b (a 1024 window) and qwen2-vl-2b (causal) over 4096 positions
+@pytest.mark.parametrize("hq,hkv,d,window", [(25, 5, 64, 1024),
+                                             (12, 2, 128, 0)],
+                         ids=["hymba-1.5b", "qwen2-vl-2b"])
+def test_fused_attention_grad_compiles_at_cell_shapes(one_chip, hq, hkv, d,
+                                                      window):
+    """The gradient of one GQA layer through the fused kernel, as a
+    training step takes it: forward, dq and dk/dv kernels, each billed
+    to the ``attention`` scope by its ``op_name``."""
+    from repro.models.attention import gqa_attention, gqa_init
+    d_model = 1536
+    p = jax.eval_shape(lambda: gqa_init(jax.random.PRNGKey(0), d_model, hq,
+                                        hkv, d))
+
+    def loss(p, x):
+        return gqa_attention(p, x, None, None, n_heads=hq, n_kv_heads=hkv,
+                             head_dim=d, window=window,
+                             impl="pallas").sum()
+
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (p, jax.ShapeDtypeStruct((1, 4096, d_model), jnp.float32)))
+    compiled = jax.jit(jax.grad(loss)).lower(*specs).compile()
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1)
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(n.rsplit("/", 2)[1] for n in names) == [
+        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
+    assert all(re.search(r"(^|[/(])attention[/)]", n) for n in names), names
+
+
+def _gqa_archs():
+    """Each architecture of the zoo whose self-attention is GQA through
+    ``models/attention.full_attention`` (MLA, RWKV and the CNN are not),
+    by (heads, kv heads, head dim, window)."""
+    from repro.configs import get_config, list_archs
+    shapes = {}
+    for arch in list_archs():
+        c = get_config(arch)
+        if c.n_kv_heads and not c.kv_lora_rank:
+            shapes[arch] = (c.n_heads, c.n_kv_heads, c.resolved_head_dim,
+                            c.sliding_window)
+    return shapes
+
+
+GQA_ARCHS = _gqa_archs()
+
+
+@pytest.mark.parametrize("arch", sorted(GQA_ARCHS))
+def test_fused_attention_grad_compiles_for_every_gqa_arch(one_chip, arch):
+    """The dispatch takes the fused kernel at every GQA shape of the zoo
+    over 4096 positions, at the block whose VMEM ``blocks`` finds to fit,
+    and the compiler takes all three kernels within ``VMEM_LIMIT``."""
+    from repro.kernels import flash_attention as fa
+    from repro.models import attention as A
+    hq, hkv, d, window = GQA_ARCHS[arch]
+
+    def loss(q, k, v):
+        return A.full_attention(q, k, v, window=window, impl="pallas").sum()
+
+    specs = [jax.ShapeDtypeStruct((1, 4096, h, d), jnp.float32,
+                                  sharding=one_chip) for h in (hq, hkv, hkv)]
+    with A.DispatchRecord() as rec:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+            .lower(*specs).compile()
+    assert [c.path for c in rec.calls] == ["fused"], rec.describe()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 3
+    assert fa.vmem_bytes(hq // hkv, d, fa.blocks(4096, window, hq // hkv, d)) \
+        <= fa.VMEM_LIMIT
+
+
+# (query heads a kv head, head dim, block): the cells' shapes and the
+# zoo's larger groups at two block sizes
+@pytest.mark.parametrize("g,d,b", [(5, 64, 512), (6, 128, 512),
+                                   (8, 128, 512), (12, 128, 256),
+                                   (12, 128, 512)])
+def test_fused_attention_fits_the_vmem_it_is_given(one_chip, monkeypatch,
+                                                   g, d, b):
+    """``vmem_bytes`` is at or above what the compiler needs: all three
+    kernels compile with their scoped VMEM limited to its estimate."""
+    from repro.kernels import flash_attention as fa
+    monkeypatch.setattr(fa, "VMEM_LIMIT", fa.vmem_bytes(g, d, b))
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, block_q=b, block_k=b).sum()
+
+    compiled = compile_for(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+                           ((1, 4096, g, d), jnp.float32),
+                           ((1, 4096, 1, d), jnp.float32),
+                           ((1, 4096, 1, d), jnp.float32))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 3
 
 
 def test_qint8_pack_compiles_at_bucket_row(one_chip):

@@ -1,11 +1,15 @@
 """Pallas kernel validation: shape/dtype sweeps, interpret=True vs the
 pure-jnp oracles in kernels/ref.py."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import (FIRST, LAST, MASKED,
+                                           flash_attention, tiles)
 
 ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -19,6 +23,88 @@ def _tol(dtype):
 _slow = pytest.mark.slow
 
 
+# The fused attention kernel rounds each matmul's operands to bfloat16
+# once (the TPU's default precision for float32): against the float32
+# oracle it is held to the bfloat16 tolerance whatever the input dtype,
+# and against the same arithmetic with that rounding (_contract_attention)
+# to CONTRACT_TOL, the norm of the difference over the norm of the
+# oracle's value.  Only the accumulation order differs there, which moves
+# a rare bfloat16 rounding of a probability (readings 2e-5 to 4.4e-5 at
+# 512 positions); a statistic, accumulator, log-sum-exp or output kept
+# in bfloat16, or a probability rounded twice, reads 1e-3 and more.
+ATTN_TOL = ATOL[jnp.bfloat16]
+CONTRACT_TOL = 1e-4
+NEG_INF = -0.7 * float(np.finfo(np.float32).max)    # the kernel's
+
+
+def _qkv(seed, b, s, hq, hkv, d, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, s, hq, d), dtype),
+            jax.random.normal(ks[1], (b, s, hkv, d), dtype),
+            jax.random.normal(ks[2], (b, s, hkv, d), dtype))
+
+
+def _close(got, want, tol=ATTN_TOL):
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol,
+                               rtol=tol)
+
+
+def _bf16(x):
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _contract_attention(q, k, v, do=None, *, window=0, block_k):
+    """Causal (windowed) GQA attention and its (q, k, v) gradients for a
+    cotangent ``do``, by the kernel's numerics contract written plainly
+    over whole arrays: q, k, v, do, each probability tile and ds rounded
+    to bfloat16 once before their matmul, everything else float32.  The
+    forward is the online softmax over key tiles of ``block_k``, each
+    tile's probabilities rounded against the running max; the backward
+    recomputes them from the log-sum-exp.  A tile hidden from a row adds
+    exactly nothing, so no tile list is needed."""
+    hi = jax.lax.Precision.HIGHEST
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    g, scale = hq // hkv, 1.0 / d ** 0.5
+    qb = _bf16(q).reshape(b, s, hkv, g, d)
+    kb, vb = _bf16(k), _bf16(v)
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    vis = (j <= i) & ((i - j < window) if window else True)
+    st = jnp.where(vis, jnp.einsum("bskgd,btkd->bkgst", qb, kb,
+                                   precision=hi) * scale, NEG_INF)
+    m = jnp.full(st.shape[:-1] + (1,), NEG_INF)
+    l, acc = jnp.zeros_like(m), 0.0
+    for t in range(0, s, block_k):
+        tile = st[..., t:t + block_k]
+        m_next = jnp.maximum(m, tile.max(axis=-1, keepdims=True))
+        p, alpha = jnp.exp(tile - m_next), jnp.exp(m - m_next)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum("bkgst,btkd->bkgsd", _bf16(p),
+                                       vb[:, t:t + block_k], precision=hi)
+        m = m_next
+    o = acc / l
+    out = {"o": o.transpose(0, 3, 1, 2, 4).reshape(q.shape)}
+    if do is None:
+        return out
+    p = jnp.exp(st - (m + jnp.log(l)))
+    dob = _bf16(do).reshape(b, s, hkv, g, d)
+    di = jnp.einsum("bskgd,bkgsd->bkgs", do.reshape(b, s, hkv, g, d), o,
+                    precision=hi)[..., None]
+    ds = _bf16(p * (jnp.einsum("bskgd,btkd->bkgst", dob, vb, precision=hi)
+                    - di))
+    out["dq"] = (jnp.einsum("bkgst,btkd->bskgd", ds, kb, precision=hi)
+                 * scale).reshape(q.shape)
+    out["dk"] = jnp.einsum("bkgst,bskgd->btkd", ds, qb, precision=hi) * scale
+    out["dv"] = jnp.einsum("bkgst,bskgd->btkd", _bf16(p), dob, precision=hi)
+    return out
+
+
+def _contract_gap(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
 @pytest.mark.parametrize("b,s,hq,hkv,d", [
     (1, 128, 1, 1, 64),
     pytest.param(2, 256, 4, 2, 64, marks=_slow),
@@ -28,43 +114,117 @@ _slow = pytest.mark.slow
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_causal(b, s, hq, hkv, d, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, s, hq, d), dtype)
-    k = jax.random.normal(ks[1], (b, s, hkv, d), dtype)
-    v = jax.random.normal(ks[2], (b, s, hkv, d), dtype)
+    q, k, v = _qkv(0, b, s, hq, hkv, d, dtype)
     o_ref = ref.flash_attention_ref(q, k, v, causal=True)
-    o = ops.flash_attention(q, k, v, causal=True, impl="pallas_interpret")
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(o_ref, np.float32),
-                               atol=_tol(dtype), rtol=_tol(dtype))
+    o = flash_attention(q, k, v, block_q=128, block_k=128, interpret=True)
+    assert o.dtype == dtype
+    _close(o, o_ref)
+    want = _contract_attention(q, k, v, block_k=128)["o"].astype(dtype)
+    assert _contract_gap(o, want) < CONTRACT_TOL
 
 
 @pytest.mark.parametrize("window", [32, 100, 256])
 def test_flash_attention_window(window):
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    b, s, hq, hkv, d = 2, 256, 4, 2, 64
-    q = jax.random.normal(ks[0], (b, s, hq, d))
-    k = jax.random.normal(ks[1], (b, s, hkv, d))
-    v = jax.random.normal(ks[2], (b, s, hkv, d))
+    """64-position blocks over 256: the window hides whole key tiles."""
+    q, k, v = _qkv(1, 2, 256, 4, 2, 64)
     o_ref = ref.flash_attention_ref(q, k, v, causal=True, window=window)
-    o = ops.flash_attention(q, k, v, causal=True, window=window,
-                            impl="pallas_interpret")
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5,
-                               rtol=2e-5)
+    o = flash_attention(q, k, v, window=window, block_q=64, block_k=64,
+                        interpret=True)
+    _close(o, o_ref)
+    want = _contract_attention(q, k, v, window=window, block_k=64)["o"]
+    assert _contract_gap(o, want) < CONTRACT_TOL
 
 
 @pytest.mark.parametrize("block_q,block_k", [(64, 64), (128, 64), (64, 128)])
 def test_flash_attention_blocks(block_q, block_k):
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    b, s, h, d = 1, 256, 2, 64
-    q = jax.random.normal(ks[0], (b, s, h, d))
-    k = jax.random.normal(ks[1], (b, s, h, d))
-    v = jax.random.normal(ks[2], (b, s, h, d))
+    q, k, v = _qkv(2, 1, 256, 2, 2, 64)
     o_ref = ref.flash_attention_ref(q, k, v, causal=True)
-    o = ops.flash_attention(q, k, v, causal=True, impl="pallas_interpret",
-                            block_q=block_q, block_k=block_k)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5,
-                               rtol=2e-5)
+    o = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                        interpret=True)
+    _close(o, o_ref)
+    want = _contract_attention(q, k, v, block_k=block_k)["o"]
+    assert _contract_gap(o, want) < CONTRACT_TOL
+
+
+def test_flash_attention_tiles_skip_hidden_blocks():
+    """The tile list holds exactly the tiles with a visible key, each
+    flagged for masking iff it straddles the mask's edge, in rows."""
+    s, b, window = 1024, 128, 300
+    q = np.arange(s)[:, None]
+    key = np.arange(s)[None, :]
+    vis = (key <= q) & (q - key < window)
+    for by_key in (False, True):
+        rows, cols, flags = tiles(s, b, b, window, by_key=by_key)
+        got = set()
+        for r, c, f in zip(rows, cols, flags):
+            i, j = (c, r) if by_key else (r, c)
+            blk = vis[i * b:(i + 1) * b, j * b:(j + 1) * b]
+            assert blk.any()
+            assert bool(f & MASKED) == (not blk.all())
+            got.add((i, j))
+        want = {(i, j) for i in range(s // b) for j in range(s // b)
+                if vis[i * b:(i + 1) * b, j * b:(j + 1) * b].any()}
+        assert got == want
+        assert np.all(np.diff(rows) >= 0)
+        starts = np.r_[True, rows[1:] != rows[:-1]]
+        ends = np.r_[rows[1:] != rows[:-1], True]
+        np.testing.assert_array_equal((flags & FIRST) != 0, starts)
+        np.testing.assert_array_equal((flags & LAST) != 0, ends)
+
+
+# the cells' attention at a small sequence: 128-position blocks over 512
+# positions; a window that is not a multiple of the block
+FUSED_CASES = {
+    "causal-g6-d128": dict(b=1, s=512, hq=12, hkv=2, d=128, window=0),
+    "window-g5-d64": dict(b=1, s=512, hq=10, hkv=2, d=64, window=200),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_vs_references(case):
+    """Outputs and (q, k, v) gradients of the fused kernel, of the float32
+    oracle, of the XLA path the models ran before (query chunks) and of
+    the rounding contract."""
+    from repro.models.attention import _chunked_causal_attend
+    c = FUSED_CASES[case]
+    q, k, v = _qkv(3, c["b"], c["s"], c["hq"], c["hkv"], c["d"])
+    do = jax.random.normal(jax.random.PRNGKey(4), q.shape)
+    w, scale = c["window"], 1.0 / c["d"] ** 0.5
+    impls = {
+        "fused": lambda q, k, v: flash_attention(
+            q, k, v, window=w, block_q=128, block_k=128, interpret=True),
+        "ref": lambda q, k, v: ref.flash_attention_ref(
+            q, k, v, causal=True, window=w),
+        "xla": lambda q, k, v: _chunked_causal_attend(
+            q, k, v, window=w, scale=scale, q_chunk=128),
+    }
+    out = {}
+    for name, fn in impls.items():
+        o, vjp = jax.vjp(fn, q, k, v)
+        out[name] = dict(zip(("o", "dq", "dk", "dv"), (o, *vjp(do))))
+    out["contract"] = _contract_attention(q, k, v, do, window=w,
+                                          block_k=128)
+    return out
+
+
+@pytest.mark.parametrize("quantity", ["o", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_attention_matches_oracle_and_xla(case, quantity):
+    res = _fused_vs_references(case)
+    got = np.asarray(res["fused"][quantity])
+    assert got.dtype == np.float32
+    for other in ("ref", "xla"):
+        want = np.asarray(res[other][quantity])
+        err = np.abs(got - want).max() / np.abs(want).max()
+        assert err < ATTN_TOL, (other, err)
+
+
+@pytest.mark.parametrize("quantity", ["o", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_attention_follows_the_rounding_contract(case, quantity):
+    res = _fused_vs_references(case)
+    gap = _contract_gap(res["fused"][quantity], res["contract"][quantity])
+    assert gap < CONTRACT_TOL, gap
 
 
 @pytest.mark.parametrize("compaction", ["scan", "onehot"])

@@ -158,3 +158,33 @@ def test_the_step_api_reduces_each_level_under_its_scope(cls_task, elastic):
     assert scoped == {"reduce.local", "reduce.pod", "reduce.global"}
     dots = [p for prim, p in paths if prim == "dot_general"]
     assert dots and not any(TOKEN.search(p) for p in dots)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_fused_attention_kernels_run_under_the_attention_scope(remat):
+    """The fused kernel's forward, dq and dk/dv calls and the XLA work
+    around them (head-major copies, the backward's row sums) sit under
+    ``attention``: its ``custom_vjp`` backward is traced apart from the
+    forward, inside the transpose of the caller's scope."""
+    import jax.numpy as jnp
+    from repro.models.attention import gqa_attention, gqa_init
+    p = gqa_init(jax.random.PRNGKey(0), 128, 4, 2, 64)
+    x = jnp.ones((1, 256, 128))
+
+    def loss(p, x):
+        out = gqa_attention(p, x, None, None, n_heads=4, n_kv_heads=2,
+                            head_dim=64, window=100, impl="pallas")
+        with jax.named_scope("head_loss"):
+            return out.sum()
+
+    fn = jax.grad(jax.checkpoint(loss) if remat else loss)
+    paths = list(_paths(jax.make_jaxpr(fn)(p, x).jaxpr))
+    kernels = sorted(path.rsplit("/", 1)[1] for prim, path in paths
+                     if prim == "pallas_call")
+    assert kernels == ["flash_attention_dkv", "flash_attention_dq"] + \
+        ["flash_attention_fwd"] * (2 if remat else 1)
+    work = [path for prim, path in paths
+            if prim in ("pallas_call", "transpose", "reduce_sum",
+                        "dot_general")]
+    unscoped = [w for w in work if not TOKEN.search(w)]
+    assert not unscoped, unscoped[:5]
