@@ -11,7 +11,8 @@ reference held in bfloat16, the precision below the configuration's
 float32) and the planted faults against the reference, which give the
 upper readings:
 
-* ``half_batch``: the loss taken over half of each sequence's positions;
+* ``half_batch``: the cross-entropy taken over half of each sequence's
+  positions;
 * ``no_exchange`` (cells with more than one learner): the groups' means
   left out.
 
@@ -32,11 +33,12 @@ from chipbench import bench, check, device, window  # noqa: E402
 
 
 def half_batch_loss(params, rows, cfg):
-    """The fault: the mean over the first half of the positions alone."""
+    """The fault: the cross-entropy's mean over the first half of the
+    positions alone; the family's loss term stays as it is."""
     from chipbench import reference as R
-    h = R.hidden(params, rows, cfg)
+    h, extra = R.hidden(params, rows, cfg)
     n = h.shape[1] // 2
-    return R.mean_nll(params, h[:, :n], rows["labels"][:, :n])
+    return R.mean_nll(params, h[:, :n], rows["labels"][:, :n]) + extra
 
 
 def program_side(cell, seed, chips):

@@ -55,20 +55,37 @@ def mlp_params(cfg: Dict) -> int:
     return 3 * cfg["d_model"] * cfg["d_ff"]
 
 
+def stack_flops(cfg: Dict, seq: int) -> int:
+    """Forward operations of the family's layers over one sequence: its
+    own count of its stack, or ``n_layers`` of its one kind of layer."""
+    fam = families.of(cfg)
+    if hasattr(fam, "stack_flops"):
+        return fam.stack_flops(cfg, seq)
+    return cfg["n_layers"] * fam.layer_flops(cfg, seq)
+
+
+def stack_params(cfg: Dict) -> int:
+    """Parameters of the family's layers, counted as :func:`stack_flops`
+    counts their operations."""
+    fam = families.of(cfg)
+    if hasattr(fam, "stack_params"):
+        return fam.stack_params(cfg)
+    return cfg["n_layers"] * fam.layer_params(cfg)
+
+
 def forward_flops(cfg: Dict, seq: int) -> int:
     """Forward operations of one sequence of ``seq`` positions: the
     family's layers and the head over the positions that carry a
     label."""
-    layer = families.get(cfg["family"]).layer_flops(cfg, seq)
     labelled = seq - vision_positions(cfg, seq)
     head = 2 * cfg["d_model"] * padded_vocab(cfg) * labelled
-    return cfg["n_layers"] * layer + head
+    return stack_flops(cfg, seq) + head
 
 
 def vision_positions(cfg: Dict, seq: int) -> int:
     """Positions ahead of the text that carry no label (0 where the
     family has none)."""
-    fam = families.get(cfg["family"])
+    fam = families.of(cfg)
     return fam.vision_positions(cfg, seq) if hasattr(
         fam, "vision_positions") else 0
 
@@ -106,6 +123,5 @@ def param_count(cfg: Dict) -> int:
     """One learner's parameters, as the configuration's training job holds
     them (padded vocabulary rows included)."""
     d, vp = cfg["d_model"], padded_vocab(cfg)
-    layer = families.get(cfg["family"]).layer_params(cfg)
     head = 0 if cfg.get("tie_embeddings") else d * vp
-    return cfg["n_layers"] * layer + vp * d + head + d
+    return stack_params(cfg) + vp * d + head + d

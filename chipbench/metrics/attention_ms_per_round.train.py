@@ -5,6 +5,8 @@ round: each operation's self time, billed to the innermost scope its
 ``tf_op`` names (``scopes.py``)."""
 from chipbench import scopes
 
+SCOPE = "attention"
+
 
 def read(ctx):
-    return scopes.read(ctx, "attention")
+    return scopes.read(ctx, SCOPE)

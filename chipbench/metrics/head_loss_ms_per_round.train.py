@@ -4,6 +4,8 @@ and the softmax cross-entropy of ``models/transformer.py``'s
 chip and window round, as for ``attention_ms_per_round.train``."""
 from chipbench import scopes
 
+SCOPE = "head_loss"
+
 
 def read(ctx):
-    return scopes.read(ctx, "head_loss")
+    return scopes.read(ctx, SCOPE)

@@ -3,6 +3,8 @@
 round, as for ``attention_ms_per_round.train``."""
 from chipbench import scopes
 
+SCOPE = "mlp"
+
 
 def read(ctx):
-    return scopes.read(ctx, "mlp")
+    return scopes.read(ctx, SCOPE)

@@ -4,6 +4,8 @@ chunked selective scan, forward and backward), ms per chip and window
 round, as for ``attention_ms_per_round.train``."""
 from chipbench import scopes
 
+SCOPE = "ssm"
+
 
 def read(ctx):
-    return scopes.read(ctx, "ssm")
+    return scopes.read(ctx, SCOPE)

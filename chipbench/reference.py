@@ -5,8 +5,9 @@ the families share (GQA attention with RoPE or M-RoPE, SwiGLU MLP,
 RMSNorm), the embedding and the cross-entropy loss, plain SGD, and the
 Hier-AVG schedule: each plan level averages its group of learners every
 ``period`` steps, inner levels first, a ``qint8`` level after each
-learner's blockwise int8 round trip.  Each family's layer and weights
-are its module under ``families/``, found by the file's ``family``.
+learner's blockwise int8 round trip.  Each family's layers, weights and
+any loss term of its own are its module under ``families/``
+(``families.of``).
 
 It imports nothing of the program (only the benchmark's own counts).
 Weights and rows are made from the seed by the recipe the
@@ -121,7 +122,7 @@ def embed_init(key, cfg):
 
 def init_params(cfg, seed: int) -> Params:
     """One learner's initial weights; every learner starts from them."""
-    return families.get(cfg["family"]).init(jax.random.PRNGKey(seed), cfg)
+    return families.of(cfg).init(jax.random.PRNGKey(seed), cfg)
 
 
 def _mrope_positions(nv: int, st: int):
@@ -227,22 +228,31 @@ def swiglu(p, x):
     return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
 
 
-def hidden(params: Params, rows, cfg):
-    """Final-norm hidden states of the positions that carry a label."""
-    x = params["embed"][rows["tokens"]]
-    nv = 0
-    if "vision_embeds" in rows:
-        nv = rows["vision_embeds"].shape[1]
-        x = jnp.concatenate([rows["vision_embeds"].astype(x.dtype), x], 1)
+def layer_stack(params: Params, x, cfg):
+    """The stack of a family of one kind of layer: ``params["layers"]``
+    scanned through its ``block`` under RoPE (or M-RoPE) angles, with no
+    loss term of its own."""
     ang = _rope_angles(cfg, x.shape[1])
-
-    block = families.get(cfg["family"]).block
+    block = families.of(cfg).block
 
     def layer(x, lp):
         return jax.checkpoint(lambda x, lp: block(lp, x, ang, cfg))(x, lp), None
 
     x, _ = jax.lax.scan(layer, x, params["layers"])
-    return rms(params["final_norm"], x, cfg["norm_eps"])[:, nv:]
+    return x, 0.0
+
+
+def hidden(params: Params, rows, cfg):
+    """Final-norm hidden states of the positions that carry a label, and
+    the family's loss term (``families/__init__.py``)."""
+    x = params["embed"][rows["tokens"]]
+    nv = 0
+    if "vision_embeds" in rows:
+        nv = rows["vision_embeds"].shape[1]
+        x = jnp.concatenate([rows["vision_embeds"].astype(x.dtype), x], 1)
+    stack = getattr(families.of(cfg), "stack", layer_stack)
+    x, extra = stack(params, x, cfg)
+    return rms(params["final_norm"], x, cfg["norm_eps"])[:, nv:], extra
 
 
 def mean_nll(params: Params, h, labels) -> jax.Array:
@@ -263,8 +273,10 @@ def mean_nll(params: Params, h, labels) -> jax.Array:
 
 
 def loss(params: Params, rows, cfg) -> jax.Array:
-    """Mean next-token cross-entropy of one learner's rows."""
-    return mean_nll(params, hidden(params, rows, cfg), rows["labels"])
+    """Mean next-token cross-entropy of one learner's rows, plus the
+    family's loss term."""
+    h, extra = hidden(params, rows, cfg)
+    return mean_nll(params, h, rows["labels"]) + extra
 
 
 # --------------------------------------------------------------------- #

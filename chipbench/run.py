@@ -53,11 +53,34 @@ def parse(argv):
     return ap.parse_args(argv)
 
 
+class _Built(Exception):
+    """Raised in place of building the model, with the configuration."""
+
+
+def arch_config(argv):
+    """The ``ArchConfig`` that ``repro.launch.train.main`` trains for
+    ``argv``: ``main`` itself parses the arguments and makes the
+    configuration (``get_config``, ``--reduced``, ``--layers`` and any
+    later setting), and stops where it hands it to ``build``; nothing is
+    built."""
+    from repro.launch import train
+
+    def build(cfg):
+        raise _Built(cfg)
+
+    real, train.build = train.build, build
+    try:
+        train.main(argv)
+    except _Built as e:
+        return e.args[0]
+    finally:
+        train.build = real
+    raise RuntimeError("train.main returned without building its model")
+
+
 def program_config(cell):
-    import dataclasses
-    from repro.configs import get_config
-    return dataclasses.replace(get_config(cell.config["arch"]),
-                               n_layers=cell.config["n_layers"])
+    """The configuration the program trains for this cell's argv."""
+    return arch_config(bench.program_argv(cell, seed=0, rounds=1))
 
 
 def run(argv=None, *, root=REPO, chips=None, peaks=None) -> dict:
@@ -124,9 +147,9 @@ def run(argv=None, *, root=REPO, chips=None, peaks=None) -> dict:
         from chipbench import trace
         red = trace.reduce(trace_dir / "window", len(chips))
         ctx = trace.Context(cell=cell, peaks=peaks, trace=red,
-                            chips=len(chips))
+                            chips=len(chips), counters=w.counters)
         for m in cell.per_layer:
-            value = bench.reader(m["name"])(ctx)
+            value = bench.reader(m["name"], cell.home)(ctx)
             if value is not None:
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}

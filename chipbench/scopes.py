@@ -3,7 +3,10 @@ gives its operations.
 
 The program runs each layer of its round under a ``jax.named_scope``
 (``repro/telemetry/spans.py``): ``attention``, ``mlp``, ``ssm``,
-``head_loss`` and one ``reduce.<level>`` per plan level.  The names
+``head_loss`` and one ``reduce.<level>`` per plan level.  The scopes
+billed are the ``reduce.<level>`` ones and those the metric files
+declare (``SCOPE = "<name>"`` in ``metrics/<metric>.py``,
+:func:`declared`), so a new metric file bills a new scope.  The names
 ride each operation's metadata into the device trace: every operation
 of a TPU plane has an entry in the plane's event metadata whose
 ``tf_op`` stat is its ``op_name`` path, for example
@@ -12,13 +15,11 @@ A scope opened directly under a transform shows inside its parentheses,
 ``jvp(vmap(head_loss))``.
 
 Each operation of the ``XLA Ops`` line is billed its self time inside
-the traced rounds, as ``trace.py`` computes it (:func:`trace.self_times`),
-to the innermost scope its ``tf_op`` names, or to ``unscoped``.  The
-result is ms per chip per window round, per scope.  The scopes add up to
-``trace.py``'s operation times, which is the busy time where operations
-nest; an operation that overlaps another without nesting is billed as
-its child, so the sum falls short of the result line's ``busy_s`` by
-the overlap, and the gap shows a trace whose operations do not nest.
+the traced rounds, as ``trace.py`` computes it (:func:`trace.self_times`:
+each instant to the latest-started operation running then), to the
+innermost scope its ``tf_op`` names, or to ``unscoped``.  The result is
+ms per chip per window round, per scope.  The scopes add up to
+``trace.py``'s operation times, which is the result line's ``busy_s``.
 
 ``jax.profiler.ProfileData`` exposes an event's own stats but not its
 metadata's, so the trace is read here with a short protobuf wire-format
@@ -32,19 +33,35 @@ import functools
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from chipbench import trace
+from chipbench.bench import metric_module
 
-SCOPE = re.compile(
-    r"(?:^|[/(])(attention|mlp|ssm|head_loss|reduce\.[\w-]+)(?=[/)]|$)")
+REDUCE = r"reduce\.[\w-]+"
 UNSCOPED = "unscoped"
-TRACES = Path(__file__).resolve().parents[1] / ".chipbench_traces"
+HERE = Path(__file__).resolve().parent
+TRACES = HERE.parent / ".chipbench_traces"
 
 
-def scope_of(tf_op: str) -> str:
-    """The innermost scope an operation's ``op_name`` path names."""
-    found = SCOPE.findall(tf_op)
+@functools.lru_cache(maxsize=8)
+def declared(metrics_dir: Path = HERE / "metrics") -> Tuple[str, ...]:
+    """The scope names the metric files in ``metrics_dir`` declare."""
+    names = {getattr(metric_module(p), "SCOPE", None)
+             for p in Path(metrics_dir).glob("*.py")}
+    return tuple(sorted(n for n in names if n))
+
+
+@functools.lru_cache(maxsize=8)
+def _pattern(names: Tuple[str, ...]) -> "re.Pattern":
+    alts = "|".join([re.escape(n) for n in names] + [REDUCE])
+    return re.compile(r"(?:^|[/(])(" + alts + r")(?=[/)]|$)")
+
+
+def scope_of(tf_op: str, names: Optional[Tuple[str, ...]] = None) -> str:
+    """The innermost scope an operation's ``op_name`` path names, of
+    ``names`` (default: :func:`declared`) and ``reduce.<level>``."""
+    found = _pattern(declared() if names is None else names).findall(tf_op)
     return found[-1] if found else UNSCOPED
 
 
@@ -227,10 +244,11 @@ def read_planes(path: Path, chips: int) -> List[Plane]:
 
 # --------------------------------------------------------------------- #
 
-def ms_per_round(planes, chips: int) -> Dict[str, float]:
-    """Self time of the traced rounds' operations per scope and
-    ``unscoped``, in ms per chip (of the first ``chips`` TPU planes) and
-    traced round."""
+def ms_per_round(planes, chips: int,
+                 names: Optional[Tuple[str, ...]] = None) -> Dict[str, float]:
+    """Self time of the traced rounds' operations per scope (of
+    ``names``, as :func:`scope_of` takes them) and ``unscoped``, in ms
+    per chip (of the first ``chips`` TPU planes) and traced round."""
     rounds = trace._host_rounds(planes)
     if not rounds:
         raise ValueError("the trace holds no round[r] annotations")
@@ -246,19 +264,20 @@ def ms_per_round(planes, chips: int) -> Dict[str, float]:
                 t1 = t0 + ev.duration_ns * 1e-9
                 a, b = max(t0, lo), min(t1, hi)
                 if b > a:
-                    events.append((scope_of(ev.tf_op), a, b))
+                    events.append((scope_of(ev.tf_op, names), a, b))
             for scope, _, _, own in trace.self_times(events):
                 totals[scope] = totals.get(scope, 0.0) + own
     return {k: 1e3 * v / chips / len(rounds) for k, v in totals.items()}
 
 
 @functools.lru_cache(maxsize=4)
-def _cached(path: str, mtime: float, chips: int) -> Dict[str, float]:
-    return ms_per_round(read_planes(Path(path), chips), chips)
+def _cached(path: str, mtime: float, chips: int,
+            names: Optional[Tuple[str, ...]]) -> Dict[str, float]:
+    return ms_per_round(read_planes(Path(path), chips), chips, names)
 
 
-def for_cell(cell: str, chips: int,
-             traces: Optional[Path] = None) -> Dict[str, float]:
+def for_cell(cell: str, chips: int, traces: Optional[Path] = None,
+             names: Optional[Tuple[str, ...]] = None) -> Dict[str, float]:
     """:func:`ms_per_round` of the newest window trace of ``cell``; empty
     where there is none."""
     traces = TRACES if traces is None else Path(traces)
@@ -268,11 +287,14 @@ def for_cell(cell: str, chips: int,
                    key=lambda p: p.stat().st_mtime)
     if not files:
         return {}
-    return _cached(str(files[-1]), files[-1].stat().st_mtime, chips)
+    return _cached(str(files[-1]), files[-1].stat().st_mtime, chips,
+                   names)
 
 
 def read(ctx, scope: str) -> Optional[float]:
-    """A reader's value: ms per chip and window round under ``scope``;
-    None where it has no operations."""
-    value = for_cell(ctx.cell.name, ctx.chips).get(scope, 0.0)
+    """A reader's value: ms per chip and window round under ``scope``,
+    billed among the scopes the cell's metric files declare; None where
+    it has no operations."""
+    names = declared(Path(ctx.cell.home) / "metrics")
+    value = for_cell(ctx.cell.name, ctx.chips, names=names).get(scope, 0.0)
     return value if value > 0 else None

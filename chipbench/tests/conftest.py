@@ -27,29 +27,27 @@ TINY_SEQ = 64
 HIER = {"name": "qwen2-vl-2b.hier.p4-qint8", "config": "qwen2-vl-2b",
         "traffic": "hier.p4-qint8", "chips": 1, "why": "tests only",
         "limits": "qwen2-vl-2b.train.p1"}
+# notes on the real size, which a tiny twin does not share
+NOT_COPIED = ("reduced", "compiler_bytes")
 
 
 def tiny_config(name: str) -> dict:
-    """The configuration file's keys, at the program's reduced widths,
-    under a tiny architecture registered for the purpose."""
+    """The configuration file's keys, each the program also has at the
+    program's reduced value, under a tiny architecture registered for
+    the purpose; the file's other keys (``reference_family``,
+    ``program_args``, notes) as they are."""
     from repro.configs import get_config
     from repro.configs.base import register
     real = json.loads((BENCH / "configs" / f"{name}.json").read_text())
     pc = get_config(real["arch"]).reduced()
     register(f"tiny-{real['arch']}")(lambda: pc)
-    out = {k: v for k, v in real.items()
-           if k in ("family", "tie_embeddings", "rope_theta", "norm_eps",
-                    "act")}
+    out = {k: v for k, v in real.items() if k not in NOT_COPIED}
+    for k in out:
+        if hasattr(pc, k):
+            v = getattr(pc, k)
+            out[k] = list(v) if isinstance(v, tuple) else v
     out.update(name=f"tiny-{name}", source=real["source"],
-               arch=f"tiny-{real['arch']}", n_layers=pc.n_layers,
-               d_model=pc.d_model, n_heads=pc.n_heads,
-               n_kv_heads=pc.n_kv_heads, head_dim=pc.resolved_head_dim,
-               d_ff=pc.d_ff, vocab_size=pc.vocab_size,
-               sliding_window=pc.sliding_window,
-               frontend_tokens=pc.frontend_tokens,
-               ssm_state=pc.ssm_state, ssm_expand=pc.ssm_expand)
-    if pc.mrope:
-        out["mrope_sections"] = list(pc.mrope_sections)
+               arch=f"tiny-{real['arch']}", head_dim=pc.resolved_head_dim)
     return out
 
 
@@ -59,6 +57,8 @@ def make_root(tmp: Path) -> Path:
     (tmp / "chipbench" / "configs").mkdir(parents=True)
     (tmp / "chipbench" / "limits").mkdir()
     shutil.copytree(BENCH / "workloads", tmp / "chipbench" / "workloads")
+    shutil.copytree(BENCH / "metrics", tmp / "chipbench" / "metrics",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     for t in (tmp / "chipbench" / "workloads").glob("*.json"):
         job = json.loads(t.read_text())
         job["seq"] = TINY_SEQ

@@ -71,3 +71,28 @@ def test_a_configuration_the_program_does_not_run_is_caught():
     cell.config = dict(cell.config, d_ff=5505, ssm_state=8)
     out = bench.config_mismatches(cell, run.program_config(cell))
     assert len(out) == 2 and out[0].startswith("d_ff")
+
+
+@pytest.mark.parametrize("extra", [[], ["--layers", "1"], ["--reduced"],
+                                   ["--reduced", "--layers", "3"]])
+def test_the_config_from_argv_is_the_one_main_trains(monkeypatch, extra):
+    """``run.arch_config`` against the configuration a whole ``train.main``
+    run hands to ``build`` and the weights it trains."""
+    import repro.launch.train as train
+    from chipbench import run
+    from conftest import tiny_config
+    argv = ["--arch", tiny_config("qwen2-vl-2b")["arch"]] + extra
+    want = run.arch_config(argv)
+    built = []
+    real = train.build
+
+    def build(cfg):
+        built.append(cfg)
+        return real(cfg)
+    monkeypatch.setattr(train, "build", build)
+    out = train.main(argv + ["--rounds", "1", "--learners", "1", "--s", "1",
+                             "--batch", "1", "--seq", "16", "--k1", "1",
+                             "--k2", "1"])
+    assert built == [want]
+    assert out.state.params["layers"]["ln1"]["scale"].shape[-2] == \
+        want.n_layers

@@ -15,6 +15,7 @@ from chipbench import bench, scopes, trace
 from chipbench.scopes import Event, Line, Plane
 
 FIXTURE = Path(__file__).resolve().parent / "fixtures" / "tiny4.xplane.pb"
+BENCH = FIXTURE.parents[2]
 
 
 @pytest.mark.parametrize("tf_op,scope", [
@@ -79,10 +80,12 @@ def test_billing_by_hand():
 
 
 def test_an_overlap_without_nesting_shows_as_a_shortfall_of_busy_time():
-    """A copy that runs on past the end of the loop it started in is
-    billed as the loop's child, as ``trace.py`` bills it: the scopes add
-    up to ``trace.py``'s operation times, short of the busy time by the
-    overlap."""
+    """A copy that runs on past the end of the loop it started in no
+    longer shows as a shortfall: each instant goes to the latest-started
+    operation running then, so the copy keeps its 10 ms, the loop loses
+    the 4 ms they share, and the scopes add up to the busy time (billed
+    as the loop's child, the copy took all 10 ms from the loop and left
+    the 6 ms past the loop's end out)."""
     ms = 1e6
     planes = _made_up()
     ops = planes[1].lines[1].events
@@ -92,11 +95,29 @@ def test_an_overlap_without_nesting_shows_as_a_shortfall_of_busy_time():
     per = scopes.ms_per_round(planes, chips=1)
     red = trace.reduce_planes(planes, chips=1)
     assert per["ssm"] == pytest.approx(5.0)
-    assert per["unscoped"] == pytest.approx((5 + 60 - 20 - 25 - 10) / 2)
+    assert per["unscoped"] == pytest.approx((5 + 60 - 20 - 25 - 4) / 2)
     assert sum(per.values()) == pytest.approx(
         1e3 * sum(red.op_seconds[0].values()) / len(red.rounds))
-    assert 1e3 * red.busy_s / len(red.rounds) - sum(per.values()) \
-        == pytest.approx(6.0 / 2)
+    assert sum(per.values()) == pytest.approx(
+        1e3 * red.busy_s / len(red.rounds))
+
+
+def test_a_loop_that_starts_under_an_async_copy_keeps_its_time():
+    """On the chip a copy's span can cover the start of the next loop
+    (``while`` under ``copy-done`` in the hymba-1.5b traces).  The loop
+    is not the copy's child: billed as one, the copy's scope lost the
+    whole loop and read below zero, and its metric went missing."""
+    ms = 1e6
+    host = Plane("/host:CPU", [Line("python3", [
+        Event("round[3]", 0, 100 * ms, "")])])
+    dev = Plane("/device:TPU:0", [Line(trace.OPS_LINE, [
+        Event("%copy-done", 5 * ms, 7 * ms, "jit(round_fn)/ssm/copy"),
+        Event("%while", 10 * ms, 80 * ms, "jit(round_fn)/while"),
+        Event("%fusion", 20 * ms, 60 * ms,
+              "jit(round_fn)/while/body/mlp/dot_general")])])
+    per = scopes.ms_per_round([host, dev], chips=1)
+    assert per == {"ssm": pytest.approx(5.0), "unscoped": pytest.approx(20.0),
+                   "mlp": pytest.approx(60.0)}
 
 
 def test_wire_reader_agrees_with_the_protobuf_classes():
@@ -138,12 +159,12 @@ def test_recorded_trace_is_all_unscoped_and_sums_to_busy_time():
     per = scopes.ms_per_round(scopes.read_planes(FIXTURE, chips=1), chips=1)
     red = trace.reduce(FIXTURE.parent, chips=1)
     assert set(per) == {"unscoped"}
-    # trace.py's self times of the operations, which nest all but for
-    # 0.09% of the busy time
+    # trace.py's self times of the operations, which add up to the busy
+    # time (0.09% of it overlaps without nesting)
     assert per["unscoped"] == pytest.approx(
         1e3 * sum(red.op_seconds[0].values()) / len(red.rounds), rel=1e-9)
     assert per["unscoped"] == pytest.approx(
-        1e3 * red.busy_s / len(red.rounds), rel=1e-3)
+        1e3 * red.busy_s / len(red.rounds), rel=1e-9)
 
 
 def test_readers_find_the_newest_window_trace_of_their_cell(tmp_path,
@@ -164,7 +185,8 @@ def test_readers_find_the_newest_window_trace_of_their_cell(tmp_path,
     assert scopes.for_cell("no-such-cell", 1, traces=tmp_path) == {}
 
     monkeypatch.setattr(scopes, "TRACES", tmp_path)
-    ctx = SimpleNamespace(cell=SimpleNamespace(name=cell), chips=1)
+    ctx = SimpleNamespace(cell=SimpleNamespace(name=cell, home=BENCH),
+                          chips=1)
     for metric, want in (("unscoped_ms_per_round.train", per["unscoped"]),
                          ("attention_ms_per_round.train", None),
                          ("mlp_ms_per_round.train", None),

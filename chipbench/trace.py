@@ -16,6 +16,7 @@ one event per operation run.  From these:
 """
 from __future__ import annotations
 
+import math
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -87,11 +88,15 @@ class Reduced:
 
 @dataclass
 class Context:
-    """What a per-layer reader is handed."""
+    """What a per-layer reader is handed.  ``counters``: the scalar
+    entries of the metrics dict of each traced round, keyed by its
+    ``round[r]`` index, as ``train.main`` holds them on the host after
+    the round's ``device_get``."""
     cell: Any
     peaks: Dict[str, Any]
     trace: Reduced
     chips: int
+    counters: Dict[int, Dict[str, float]] = field(default_factory=dict)
 
 
 def latest_xplane(directory: Path) -> Path:
@@ -181,19 +186,36 @@ def op_parts(text: str) -> Tuple[str, str]:
 
 def self_times(events) -> List[Tuple[str, float, float, float]]:
     """(text, start, end, self seconds) of each event inside the window
-    bounds given by the caller.  Operations nest on the ``XLA Ops`` line
-    (a ``while`` holds its body's operations); an event's self time is
-    its duration less its direct children's."""
-    out: List[List] = []
+    bounds given by the caller, in order of start.  Each instant goes to
+    the latest-started operation running then.  Operations nest on the
+    ``XLA Ops`` line (a ``while`` holds its body's operations), and there
+    an event's self time is its duration less its children's.  Some do
+    not nest: an asynchronous copy's span can cover the start of the next
+    loop.  Each of the two then keeps the time in which it is the latest
+    started, so no self time is negative and they add up to the busy
+    time (subtracting the loop's whole duration from the copy would bill
+    the copy's scope minus the loop)."""
+    out = sorted(([text, t0, t1, 0.0] for text, t0, t1 in events),
+                 key=lambda r: (r[1], -r[2]))
     stack: List[List] = []
-    for text, t0, t1 in sorted(events, key=lambda e: (e[1], -e[2])):
-        while stack and stack[-1][2] <= t0:
-            stack.pop()
-        rec = [text, t0, t1, t1 - t0]
-        if stack:
-            stack[-1][3] -= t1 - t0
+    now = -math.inf
+
+    def bill(until: float) -> None:
+        nonlocal now
+        while stack and now < until:
+            top = stack[-1]
+            if top[2] <= now:
+                stack.pop()
+                continue
+            end = min(top[2], until)
+            top[3] += end - now
+            now = end
+
+    for rec in out:
+        bill(rec[1])
+        now = max(now, rec[1])
         stack.append(rec)
-        out.append(rec)
+    bill(math.inf)
     return [tuple(r) for r in out]
 
 

@@ -20,7 +20,10 @@ which never pays for any of it.
 With a trace, the program is given ``--profile-dir`` so that its spans
 become profiler annotations; the hook stops the program's own profiler
 session at once and starts one of its own, without the Python tracer,
-for the window's rounds alone.
+for the window's rounds alone.  At each traced round's line it keeps
+the round's scalar metrics (``Window.counters``): ``main`` took them to
+the host before printing, so the hook copies numbers and waits for
+nothing.  An untraced run keeps none.
 """
 from __future__ import annotations
 
@@ -83,6 +86,13 @@ def change_norms(state, start: Dict[str, np.ndarray]) -> Dict[str, float]:
             for k, a in learner0_shards(state).items()}
 
 
+def scalars(metrics) -> Dict[str, float]:
+    """The scalar entries of a round's metrics dict, already on the
+    host."""
+    return {k: float(v) for k, v in metrics.items()
+            if isinstance(v, (np.ndarray, np.generic)) and v.ndim == 0}
+
+
 def abstract(tree):
     """Shapes, types and placements of a tree of arrays."""
     import jax
@@ -107,6 +117,7 @@ class Window:
     compiles_in_window: int = 0
     round_fn: Any = None           # the program's jitted round ...
     round_args: Any = None         # ... and its arguments' shapes
+    counters: Dict[int, Dict[str, float]] = field(default_factory=dict)
 
     @property
     def seconds(self) -> float:
@@ -192,6 +203,8 @@ class RoundClock(io.TextIOBase):
             return
         w.longest_round = max(w.longest_round, (now - self._last_t, r))
         self._last_t = now
+        if self.trace_dir is not None:
+            w.counters[r] = scalars(loc["m"])
         done = r - self.check_rounds + 1
         if (self.trace_dir is not None and done >= self.trace_rounds) or \
                 (self.trace_dir is None and now - w.start_t >= self.seconds):
